@@ -187,8 +187,6 @@ def sharded_batched_block_sparse_attention(
     :func:`repro.kernels.batched_sparse_attention_fn`) are expected to fall
     back to the single-device path otherwise.
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.kernels.ops import batched_block_sparse_attention
 
     if head_shard_count(mesh, axis, q.shape[1], k.shape[1]) <= 1:
@@ -204,12 +202,43 @@ def sharded_batched_block_sparse_attention(
             interpret=interpret, width=width, stats_gate=g_l)
 
     hs = P(None, axis)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(hs, hs, hs, hs, hs),
         out_specs=(hs, hs),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, block_mask, stats_gate)
+
+
+def sharded_compute_strips(
+    q: jax.Array,               # (H, N, D)
+    k: jax.Array,               # (Hkv, N, D)
+    *,
+    mesh: Mesh,
+    axis: str = "model",
+    block_size: int,
+    interpret: bool,
+) -> jax.Array:
+    """Heads-sharded strip scores, (H, block_size, N) f32.
+
+    Runs :func:`repro.kernels.strip.strip_scores_pallas` under
+    ``shard_map`` with both head axes partitioned over ``axis``: a Mosaic
+    kernel cannot be partitioned by the compiler, so under a mesh it must
+    see only its local heads.  Strips are per head, so the output equals
+    the single-device kernel's.
+    """
+    from repro.kernels.strip import strip_scores_pallas
+
+    if head_shard_count(mesh, axis, q.shape[0], k.shape[0]) <= 1:
+        raise ValueError(
+            f"head counts {q.shape[0]}/{k.shape[0]} do not shard over mesh "
+            f"axis {axis!r} of {mesh.shape}")
+    hs = P(axis)
+    return jax.shard_map(
+        lambda q_l, k_l: strip_scores_pallas(q_l, k_l, block_size=block_size,
+                                             interpret=interpret),
+        mesh=mesh, in_specs=(hs, hs), out_specs=hs, check_vma=False,
+    )(q, k)
 
 
 def sharded_flash_decode(
@@ -247,8 +276,6 @@ def sharded_flash_decode(
 
     Returns (B, H, Dv).
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.kernels.decode_attn import DecodePlan, flash_decode_plan
 
     if head_shard_count(mesh, axis, q.shape[1], cache_k.shape[1]) <= 1:
@@ -262,11 +289,11 @@ def sharded_flash_decode(
                                  valid_l, impl=impl, interpret=interpret)
 
     hs = P(None, axis)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(hs, hs, hs, hs, hs, hs, P(None, None)),
         out_specs=hs,
-        check_rep=False,
+        check_vma=False,
     )(q, cache_k, cache_v, plan.indices, plan.counts, plan.keep_heads, valid)
 
 
@@ -294,8 +321,6 @@ def sharded_flash_decode_paged(
     cross-shard reductions, so the output equals the single-device
     :func:`repro.kernels.decode_attn.flash_decode_plan_paged` bitwise.
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.kernels.decode_attn import DecodePlan, flash_decode_plan_paged
 
     if head_shard_count(mesh, axis, q.shape[1], pool_k.shape[1]) <= 1:
@@ -311,11 +336,11 @@ def sharded_flash_decode_paged(
 
     hs = P(None, axis)
     rep = P(None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(hs, hs, hs, rep, hs, hs, hs, rep),
         out_specs=hs,
-        check_rep=False,
+        check_vma=False,
     )(q, pool_k, pool_v, page_table, plan.indices, plan.counts,
       plan.keep_heads, valid)
 

@@ -235,6 +235,26 @@ def ragged_grid_steps(nbq: int, nbkv: int, *, width: Optional[int] = None,
                .shape[0])
 
 
+def _schedule_indices(indices: jnp.ndarray, row_map: np.ndarray,
+                      slot_map: np.ndarray) -> jnp.ndarray:
+    """(B, H, NBq, W) index tables → (B, H, T), the kv block of every
+    schedule step.  The scalar-prefetched table then holds only the steps
+    the ragged schedule visits: about half the rectangle under a causal
+    mask, which keeps an 8k-token block-64 table for 16 heads inside the
+    1 MiB of SMEM."""
+    return indices[:, :, row_map[:-1], slot_map]
+
+
+def _store_head_stat(stats_ref, h, val):
+    """Write head ``h``'s (1, 1) stat into lane ``h`` of the step's (1, H)
+    stats row.  A masked vector store: Mosaic cannot store a scalar to
+    VMEM.  Heads are the innermost grid axis, so the row stays resident
+    across the head sweep and every lane is written before writeback."""
+    row = stats_ref[0, 0]                                  # (1, H)
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    stats_ref[0, 0] = jnp.where(lane == h, val, row)
+
+
 def _kernel_batched(row_ref, slot_ref, idx_ref, cnt_ref, gate_ref,  # SMEM
                     q_ref, k_ref, v_ref,          # VMEM tiles
                     out_ref, stats_ref,           # outputs
@@ -254,7 +274,7 @@ def _kernel_batched(row_ref, slot_ref, idx_ref, cnt_ref, gate_ref,  # SMEM
         l_ref[h] = jnp.zeros(l_ref.shape[1:], l_ref.dtype)
 
     count = cnt_ref[b, h, row]
-    j = idx_ref[b, h, row, slot]
+    j = idx_ref[b, h, t]
     valid = slot < count
     emit_stats = valid & (gate_ref[b, h] != 0)
 
@@ -281,10 +301,10 @@ def _kernel_batched(row_ref, slot_ref, idx_ref, cnt_ref, gate_ref,  # SMEM
         # reductions entirely
         @pl.when(emit_stats)
         def _stats():
-            n_valid = jnp.sum(tok_valid.astype(jnp.float32))
-            s_sum = jnp.sum(jnp.where(tok_valid, s, 0.0))
-            stats_ref[0, 0, h] = jnp.where(
-                n_valid > 0, s_sum / jnp.maximum(n_valid, 1.0), NEG_INF)
+            n_valid = jnp.sum(tok_valid.astype(jnp.float32), keepdims=True)
+            s_sum = jnp.sum(jnp.where(tok_valid, s, 0.0), keepdims=True)
+            _store_head_stat(stats_ref, h, jnp.where(
+                n_valid > 0, s_sum / jnp.maximum(n_valid, 1.0), NEG_INF))
 
         s = jnp.where(tok_valid, s, NEG_INF)
         m_prev = m_ref[h]                           # (bq, 1)
@@ -301,7 +321,7 @@ def _kernel_batched(row_ref, slot_ref, idx_ref, cnt_ref, gate_ref,  # SMEM
 
     @pl.when(jnp.logical_not(emit_stats))
     def _no_stats():
-        stats_ref[0, 0, h] = NEG_INF
+        _store_head_stat(stats_ref, h, jnp.full((1, 1), NEG_INF, jnp.float32))
 
     @pl.when(row_ref[t + 1] != row)
     def _finalize():
@@ -380,20 +400,18 @@ def block_sparse_attention_batched(
                          (bb, 0, row[tt], 0)),
             pl.BlockSpec((1, 1, block_size, d),
                          lambda bb, tt, hh, row, slot, idx, cnt, gate:
-                         (bb, hh // group,
-                          idx[bb, hh, row[tt], slot[tt]], 0)),
+                         (bb, hh // group, idx[bb, hh, tt], 0)),
             pl.BlockSpec((1, 1, block_size, dv),
                          lambda bb, tt, hh, row, slot, idx, cnt, gate:
-                         (bb, hh // group,
-                          idx[bb, hh, row[tt], slot[tt]], 0)),
+                         (bb, hh // group, idx[bb, hh, tt], 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, h, block_size, dv),
                          lambda bb, tt, hh, row, slot, idx, cnt, gate:
                          (bb, 0, row[tt], 0)),
-            pl.BlockSpec((1, 1, h),
+            pl.BlockSpec((1, 1, 1, h),
                          lambda bb, tt, hh, row, slot, idx, cnt, gate:
-                         (bb, tt, 0)),
+                         (bb, tt, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((h, block_size, dv), jnp.float32),
@@ -407,12 +425,13 @@ def block_sparse_attention_batched(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, n, dv), q.dtype),
-            jax.ShapeDtypeStruct((b, t_steps, h), jnp.float32),
+            jax.ShapeDtypeStruct((b, t_steps, 1, h), jnp.float32),
         ],
         interpret=interpret,
-    )(jnp.asarray(row_map), jnp.asarray(slot_map), indices, counts,
+    )(jnp.asarray(row_map), jnp.asarray(slot_map),
+      _schedule_indices(indices, row_map, slot_map), counts,
       stats_gate, q, k, v)
-    return out, stats
+    return out, stats.reshape(b, t_steps, h)
 
 
 def _kernel_batched_paged(row_ref, slot_ref, idx_ref, cnt_ref, gate_ref,
@@ -484,20 +503,18 @@ def block_sparse_attention_batched_paged(
                          (bb, 0, row[tt], 0)),
             pl.BlockSpec((1, 1, block_size, d),
                          lambda bb, tt, hh, row, slot, idx, cnt, gate, pt:
-                         (pt[bb, idx[bb, hh, row[tt], slot[tt]]],
-                          hh // group, 0, 0)),
+                         (pt[bb, idx[bb, hh, tt]], hh // group, 0, 0)),
             pl.BlockSpec((1, 1, block_size, dv),
                          lambda bb, tt, hh, row, slot, idx, cnt, gate, pt:
-                         (pt[bb, idx[bb, hh, row[tt], slot[tt]]],
-                          hh // group, 0, 0)),
+                         (pt[bb, idx[bb, hh, tt]], hh // group, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, h, block_size, dv),
                          lambda bb, tt, hh, row, slot, idx, cnt, gate, pt:
                          (bb, 0, row[tt], 0)),
-            pl.BlockSpec((1, 1, h),
+            pl.BlockSpec((1, 1, 1, h),
                          lambda bb, tt, hh, row, slot, idx, cnt, gate, pt:
-                         (bb, tt, 0)),
+                         (bb, tt, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((h, block_size, dv), jnp.float32),
@@ -511,9 +528,10 @@ def block_sparse_attention_batched_paged(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, n, dv), q.dtype),
-            jax.ShapeDtypeStruct((b, t_steps, h), jnp.float32),
+            jax.ShapeDtypeStruct((b, t_steps, 1, h), jnp.float32),
         ],
         interpret=interpret,
-    )(jnp.asarray(row_map), jnp.asarray(slot_map), indices, counts,
+    )(jnp.asarray(row_map), jnp.asarray(slot_map),
+      _schedule_indices(indices, row_map, slot_map), counts,
       stats_gate, page_table, q, pool_k, pool_v)
-    return out, stats
+    return out, stats.reshape(b, t_steps, h)

@@ -294,6 +294,19 @@ def flash_decode_sparse(
 # Batched serving kernel: (B, Hkv, W) grid over prebuilt DecodePlan tables
 # --------------------------------------------------------------------------
 
+def _tile_keep_valid(keep_heads: jnp.ndarray, valid: jnp.ndarray,
+                     block_kv: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Kernel layout of the keep bits and slot validity: int32 with the
+    group and block axes last, so each grid step reads a ``(G, 1)`` and a
+    ``(1, bs)`` tile.  These equal the array's last two dims, which the TPU
+    tiling rule accepts, and the body compares against 0 rather than
+    broadcasting i1 vectors, which Mosaic cannot reshape."""
+    b, s = valid.shape
+    keep = keep_heads.astype(jnp.int32)[..., None]          # (B, Hkv, NB, G, 1)
+    val = valid.astype(jnp.int32).reshape(b, s // block_kv, 1, block_kv)
+    return keep, val
+
+
 def _batched_kernel(idx_ref, cnt_ref,             # scalar prefetch (SMEM)
                     q_ref, k_ref, v_ref, keep_ref, val_ref,   # VMEM tiles
                     out_ref, acc_ref, m_ref, l_ref,
@@ -313,11 +326,11 @@ def _batched_kernel(idx_ref, cnt_ref,             # scalar prefetch (SMEM)
         q = q_ref[0, 0].astype(jnp.float32)      # (G, D)
         k = k_ref[0, 0].astype(jnp.float32)      # (bs, D)
         v = v_ref[0, 0].astype(jnp.float32)      # (bs, Dv)
-        keep = keep_ref[0, 0, 0]                 # (G,) per-head block keep
-        tok = val_ref[0]                         # (bs,) slot validity
+        keep = keep_ref[0, 0, 0]                 # (G, 1) per-head block keep
+        tok = val_ref[0, 0]                      # (1, bs) slot validity
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        ok = keep[:, None] & tok[None, :]        # (G, bs)
+        ok = (keep != 0) & (tok != 0)            # (G, bs)
         s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -385,11 +398,12 @@ def flash_decode_sparse_batched(
             pl.BlockSpec((1, 1, block_kv, dv),
                          lambda b_, h_, w, idx, cnt:
                          (b_, h_, idx[b_, h_, w], 0)),
-            pl.BlockSpec((1, 1, 1, g),
+            pl.BlockSpec((1, 1, 1, g, 1),
                          lambda b_, h_, w, idx, cnt:
-                         (b_, h_, idx[b_, h_, w], 0)),
-            pl.BlockSpec((1, block_kv),
-                         lambda b_, h_, w, idx, cnt: (b_, idx[b_, h_, w])),
+                         (b_, h_, idx[b_, h_, w], 0, 0)),
+            pl.BlockSpec((1, 1, 1, block_kv),
+                         lambda b_, h_, w, idx, cnt:
+                         (b_, idx[b_, h_, w], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
                                lambda b_, h_, w, idx, cnt: (b_, h_, 0, 0)),
@@ -404,7 +418,8 @@ def flash_decode_sparse_batched(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
         interpret=_auto_interpret(interpret),
-    )(indices, counts, qg, cache_k, cache_v, keep_heads, valid)
+    )(indices, counts, qg, cache_k, cache_v,
+      *_tile_keep_valid(keep_heads, valid, block_kv))
     return out.reshape(b, h, dv)
 
 
@@ -621,12 +636,12 @@ def flash_decode_sparse_batched_paged(
             pl.BlockSpec((1, 1, ps, dv),
                          lambda b_, h_, w, pt, idx, cnt:
                          (pt[b_, idx[b_, h_, w]], h_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, g),
+            pl.BlockSpec((1, 1, 1, g, 1),
                          lambda b_, h_, w, pt, idx, cnt:
-                         (b_, h_, idx[b_, h_, w], 0)),
-            pl.BlockSpec((1, ps),
+                         (b_, h_, idx[b_, h_, w], 0, 0)),
+            pl.BlockSpec((1, 1, 1, ps),
                          lambda b_, h_, w, pt, idx, cnt:
-                         (b_, idx[b_, h_, w])),
+                         (b_, idx[b_, h_, w], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
                                lambda b_, h_, w, pt, idx, cnt:
@@ -645,7 +660,8 @@ def flash_decode_sparse_batched_paged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
         interpret=_auto_interpret(interpret),
-    )(page_table, indices, counts, qg, pool_k, pool_v, keep_heads, valid)
+    )(page_table, indices, counts, qg, pool_k, pool_v,
+      *_tile_keep_valid(keep_heads, valid, ps))
     return out.reshape(b, h, dv)
 
 

@@ -25,6 +25,7 @@ oracle on CPU hosts (where Pallas only interprets), the kernel on TPU.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -58,17 +59,17 @@ def strip_scores(q: jnp.ndarray, k: jnp.ndarray,
 # Pallas kernels
 # --------------------------------------------------------------------------
 
-def _tile_logits(q_ref, k_ref, j, *, block_size, n, scale):
-    """(bs, bs) scaled QK logits of kv tile j, −inf outside causality."""
+def _tile_logits(q_ref, k_ref, j, *, block_size, tile, n, scale):
+    """(bs, tile) scaled QK logits of kv tile j, −inf outside causality."""
     q = q_ref[0].astype(jnp.float32)
     k = k_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     q_pos = (n - block_size) + jax.lax.broadcasted_iota(
-        jnp.int32, (block_size, block_size), 0)
-    k_pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (block_size, block_size), 1)
+        jnp.int32, (block_size, tile), 0)
+    k_pos = j * tile + jax.lax.broadcasted_iota(
+        jnp.int32, (block_size, tile), 1)
     valid = k_pos <= q_pos
     return jnp.where(valid, s, NEG_INF), valid
 
@@ -83,8 +84,8 @@ def _strip_ml_kernel(q_ref, k_ref, m_out, l_out, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    s, valid = _tile_logits(q_ref, k_ref, j, block_size=block_size, n=n,
-                            scale=scale)
+    s, valid = _tile_logits(q_ref, k_ref, j, block_size=block_size,
+                            tile=block_size, n=n, scale=scale)
     m_prev = m_ref[...]                              # (bs, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_new), 0.0)
@@ -94,17 +95,17 @@ def _strip_ml_kernel(q_ref, k_ref, m_out, l_out, m_ref, l_ref,
 
     @pl.when(j == nb - 1)
     def _finalize():
-        m_out[0, :] = m_ref[...][:, 0]
-        l_out[0, :] = l_ref[...][:, 0]
+        m_out[0] = m_ref[...]
+        l_out[0] = l_ref[...]
 
 
 def _strip_norm_kernel(q_ref, k_ref, m_ref, l_ref, out_ref,
-                       *, block_size: int, n: int, scale: float):
+                       *, block_size: int, tile: int, n: int, scale: float):
     j = pl.program_id(1)
-    s, valid = _tile_logits(q_ref, k_ref, j, block_size=block_size, n=n,
-                            scale=scale)
-    m = m_ref[0][:, None]                            # (bs, 1)
-    l = jnp.maximum(l_ref[0][:, None], 1e-30)
+    s, valid = _tile_logits(q_ref, k_ref, j, block_size=block_size,
+                            tile=tile, n=n, scale=scale)
+    m = m_ref[0]                                     # (bs, 1)
+    l = jnp.maximum(l_ref[0], 1e-30)
     out_ref[0] = jnp.where(valid, jnp.exp(s - m), 0.0) / l
 
 
@@ -132,6 +133,9 @@ def strip_scores_pallas(
     q_spec = pl.BlockSpec((1, block_size, d), lambda hh, jj: (hh, 0, 0))
     k_spec = pl.BlockSpec((1, block_size, d),
                           lambda hh, jj: (hh // group, jj, 0))
+    # per-row (m, l) keep the scratch's (bs, 1) column layout: a (1, bs)
+    # row block of an (H, bs) array breaks the TPU (8, 128) tiling rule
+    ml_spec = pl.BlockSpec((1, block_size, 1), lambda hh, jj: (hh, 0, 0))
 
     ml_kernel = functools.partial(_strip_ml_kernel, block_size=block_size,
                                   n=n, scale=scale)
@@ -139,13 +143,10 @@ def strip_scores_pallas(
         ml_kernel,
         grid=(h, nb),
         in_specs=[q_spec, k_spec],
-        out_specs=[
-            pl.BlockSpec((1, block_size), lambda hh, jj: (hh, 0)),
-            pl.BlockSpec((1, block_size), lambda hh, jj: (hh, 0)),
-        ],
+        out_specs=[ml_spec, ml_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((h, block_size), jnp.float32),
-            jax.ShapeDtypeStruct((h, block_size), jnp.float32),
+            jax.ShapeDtypeStruct((h, block_size, 1), jnp.float32),
+            jax.ShapeDtypeStruct((h, block_size, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_size, 1), jnp.float32),
@@ -154,17 +155,24 @@ def strip_scores_pallas(
         interpret=interpret,
     )(q_hat, k)
 
+    # the strip tile's last dim is a lane dim, so it must be a multiple of
+    # 128: blocks under 128 write several kv blocks per grid step (each
+    # element's math is unchanged — the tile only sets how many are written)
+    tile = math.lcm(block_size, 128)
+    if n % tile:
+        tile = block_size
     norm_kernel = functools.partial(_strip_norm_kernel, block_size=block_size,
-                                    n=n, scale=scale)
+                                    tile=tile, n=n, scale=scale)
     strip = pl.pallas_call(
         norm_kernel,
-        grid=(h, nb),
+        grid=(h, n // tile),
         in_specs=[
-            q_spec, k_spec,
-            pl.BlockSpec((1, block_size), lambda hh, jj: (hh, 0)),
-            pl.BlockSpec((1, block_size), lambda hh, jj: (hh, 0)),
+            q_spec,
+            pl.BlockSpec((1, tile, d), lambda hh, jj: (hh // group, jj, 0)),
+            ml_spec,
+            ml_spec,
         ],
-        out_specs=pl.BlockSpec((1, block_size, block_size),
+        out_specs=pl.BlockSpec((1, block_size, tile),
                                lambda hh, jj: (hh, 0, jj)),
         out_shape=jax.ShapeDtypeStruct((h, block_size, n), jnp.float32),
         interpret=interpret,
@@ -199,8 +207,24 @@ def compute_strips(
         # silently drop keys from the softmax denominator
         impl = "jnp"
     if impl == "pallas":
+        # under a serving mesh the kernel runs per head shard (the compiler
+        # cannot partition a Mosaic kernel); heads that do not shard take
+        # the oracle
+        from repro.distributed.sharding import (
+            active_model_mesh,
+            sharded_compute_strips,
+            shardable_model_mesh,
+        )
         it = interpret if interpret is not None else not on_tpu
-        return strip_scores_pallas(q, k, block_size=block_size, interpret=it)
+        mesh = shardable_model_mesh(q.shape[0], k.shape[0])
+        if mesh is not None:
+            return sharded_compute_strips(q, k, mesh=mesh,
+                                          block_size=block_size,
+                                          interpret=it)
+        if active_model_mesh() is None:
+            return strip_scores_pallas(q, k, block_size=block_size,
+                                       interpret=it)
+        impl = "jnp"
     if impl != "jnp":
         raise ValueError(f"unknown strip impl {impl!r}")
     from repro.kernels.ops import gqa_head_vmap

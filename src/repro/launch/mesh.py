@@ -9,19 +9,33 @@ device state (the dry-run must set XLA_FLAGS before any device query).
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """Every mesh of this repo: ``jax.make_mesh`` with Auto axes.
+
+    Since JAX 0.8 ``jax.make_mesh`` defaults to Explicit axes, which
+    ``with_sharding_constraint`` (``repro.distributed.sharding.shard``)
+    refuses; the sharding rules here annotate and let the compiler
+    propagate, which needs Auto axes.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     """Small mesh for unit tests (requires ≥ prod(shape) local devices)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_serving_mesh(model_parallel: int = 0,
@@ -40,7 +54,7 @@ def make_serving_mesh(model_parallel: int = 0,
     if dp * mp > n:
         raise ValueError(f"mesh (data={dp}, model={mp}) needs {dp * mp} "
                          f"devices, have {n}")
-    return jax.make_mesh((dp, mp), ("data", "model"))
+    return make_mesh((dp, mp), ("data", "model"))
 
 
 # TPU v5e hardware constants for the roofline model (per chip).

@@ -27,11 +27,18 @@ request 0's prompt so the sharing path is observable from the CLI.
 ``--model-parallel N`` (N > 1) serves under a heads-sharded (data, model)
 mesh: the engine's sparse prefill AND sparse decode hot paths run under
 ``shard_map`` with per-shard index tables (the mesh-active routing rule —
-``repro.distributed.sharding.active_model_mesh``).  On a CPU container,
-combine with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to get
-N placeholder devices; outputs are bitwise-identical to the unsharded
-serve.  ``--decode-sparse`` additionally reuses the prefill pattern
-dictionary for decode via the build-once DecodePlan.
+``repro.distributed.sharding.active_model_mesh``), the weights are split
+over the mesh by ``repro.distributed.param_specs`` and the page pool
+along the kv heads.  On a CPU container, combine with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to get N
+placeholder devices.  Attention is head-parallel with no cross-device
+reduction, but the split projections reduce across devices, so logits
+can differ from the unsharded serve in the last bits.
+``--decode-sparse`` additionally reuses the prefill pattern dictionary
+for decode via the build-once DecodePlan.
+
+The exit code is 1 when any request ends ``failed`` (the per-request
+quarantine keeps the serve going past it), else 0.
 
 ``--refresh-every N`` (paged + ``--decode-sparse``) turns on adaptive
 pattern refresh during long decodes: every N generated tokens a slot's
@@ -45,20 +52,22 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import sys
 import time
 
 import jax
-import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.data import DataConfig, sample
+from repro.distributed.param_specs import param_shardings
 from repro.distributed.sharding import ShardingRules, use_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.models import build_model
 from repro.serving import EngineConfig, Request, ServingEngine
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -135,11 +144,31 @@ def main():
                     help="model-axis size of the serving mesh; > 1 runs "
                     "prefill and decode heads-sharded under shard_map")
     ap.add_argument("--task", default="retrieval")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def serve(args: argparse.Namespace):
+    """Build the model, requests and engine the flags describe and serve
+    the requests; returns ``(engine, requests, wall_s)``."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # weights are served in the compute dtype.  With f32 weights the
+    # activations and KV pages are f32 too, and on a TPU every step program
+    # also holds a bf16 copy of all scanned layer weights as temp (the MXU
+    # multiplies f32 at default precision in bf16): internlm2-1.8b's 4x4k
+    # serve then does not fit a 16 GB v5e.  One jitted init never holds the
+    # f32 weights: under a mesh each device makes only its own slice, split
+    # by the per-leaf specs (the page pool follows along Hkv)
+    def init(key):
+        return jax.tree.map(lambda p: p.astype(cfg.dtype), model.init(key))
+
+    key = jax.random.PRNGKey(0)
+    mesh, shardings = None, None
+    if args.model_parallel > 1:
+        mesh = make_serving_mesh(args.model_parallel)
+        shardings = param_shardings(jax.eval_shape(init, key), mesh,
+                                    fsdp=False)
+    params = jax.jit(init, out_shardings=shardings)(key)
     sp = model.default_share_prefill()
 
     dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.prompt_len,
@@ -176,8 +205,7 @@ def main():
     # one mesh for the whole serve: prefill and decode trace under the same
     # rules context, so both hot paths resolve their sharded twin
     ctx = contextlib.ExitStack()
-    if args.model_parallel > 1:
-        mesh = make_serving_mesh(args.model_parallel)
+    if mesh is not None:
         ctx.enter_context(use_rules(ShardingRules(mesh)))
         ctx.enter_context(mesh)
         print(f"serving under mesh {dict(mesh.shape)}")
@@ -186,7 +214,12 @@ def main():
         t0 = time.time()
         engine.serve(requests)
         wall = time.time() - t0
+    return engine, requests, wall
 
+
+def report(args: argparse.Namespace, engine: ServingEngine, requests,
+           wall: float) -> None:
+    """Print one line per request and the serve's summary."""
     for r in requests:
         m = r.metrics()
         lifecycle = (f" deferred={m['waiting_deferred_steps']}"
@@ -243,5 +276,20 @@ def main():
           f"phase_s={ {k: round(v, 3) for k, v in engine.phase_s.items()} }")
 
 
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
+    engine, requests, wall = serve(args)
+    report(args, engine, requests, wall)
+    # the quarantine wall keeps a serve alive past a failing request; the
+    # exit code still says that one failed
+    failed = [r.uid for r in requests if r.state == "failed"]
+    if failed:
+        print(f"error: {len(failed)} request(s) failed: uids {failed}",
+              file=sys.stderr)
+        return 1
+    return 0
+
+
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
 from repro.data import DataConfig, batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import AdamWConfig
 from repro.training import TrainConfig, train
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--ckpt-dir")
     ap.add_argument("--metrics-out")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)

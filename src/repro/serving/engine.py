@@ -489,18 +489,18 @@ class ServingEngine:
             kwargs = {} if width is None else {"attn_width": width}
 
             if ragged:
-                def fn(params, tokens, plens):
+                def prefill_step(params, tokens, plens):
                     return self.model.prefill(
                         params, tokens, self.sp, method=self.ecfg.method,
                         attn_impl=self.ecfg.attn_impl, prompt_lens=plens,
                         **kwargs)
             else:
-                def fn(params, tokens, plens):
+                def prefill_step(params, tokens, plens):
                     del plens
                     return self.model.prefill(
                         params, tokens, self.sp, method=self.ecfg.method,
                         attn_impl=self.ecfg.attn_impl, **kwargs)
-            self._prefill_cache[key] = jax.jit(fn)
+            self._prefill_cache[key] = jax.jit(prefill_step)
         return self._prefill_cache[key]
 
     def _decode_fn(self, batch: int, seq: int, cache_len: int,
@@ -525,21 +525,21 @@ class ServingEngine:
             if sparse:
                 # the jitted step consumes the prebuilt DecodePlan tables —
                 # O(L·B·Hkv·NB) — never a token-level keep mask
-                def fn(params, token, cache, pos, plens, plan):
+                def decode_step(params, token, cache, pos, plens, plan):
                     return self.model.decode(
                         params, token, cache, pos, plan=plan,
                         prompt_lens=plens, prefill_len=seq,
                         decode_impl=self.ecfg.decode_impl)
             elif thread_lens:
-                def fn(params, token, cache, pos, plens):
+                def decode_step(params, token, cache, pos, plens):
                     return self.model.decode(
                         params, token, cache, pos,
                         prompt_lens=plens, prefill_len=seq)
             else:
-                def fn(params, token, cache, pos, plens):
+                def decode_step(params, token, cache, pos, plens):
                     del plens
                     return self.model.decode(params, token, cache, pos)
-            self._decode_cache[key] = jax.jit(fn)
+            self._decode_cache[key] = jax.jit(decode_step)
         return self._decode_cache[key]
 
     def _decode_fn_paged(self, batch: int, table_blocks: int,
@@ -563,8 +563,8 @@ class ServingEngine:
                table_blocks, sparse, current_rules())
         if key not in self._decode_cache:
             if sparse and collect_queries:
-                def fn(params, token, cache, page_table, pos, plens,
-                       pflens, plan):
+                def decode_step(params, token, cache, page_table, pos,
+                                plens, pflens, plan):
                     return self.model.decode(
                         params, token, cache, pos, plan=plan,
                         prompt_lens=plens, prefill_len=pflens,
@@ -572,8 +572,8 @@ class ServingEngine:
                         decode_impl=self.ecfg.decode_impl,
                         collect_queries=True)
             elif sparse:
-                def fn(params, token, cache, page_table, pos, plens,
-                       pflens, plan):
+                def decode_step(params, token, cache, page_table, pos,
+                                plens, pflens, plan):
                     return self.model.decode(
                         params, token, cache, pos, plan=plan,
                         prompt_lens=plens, prefill_len=pflens,
@@ -584,12 +584,12 @@ class ServingEngine:
                     raise ValueError(
                         "collect_queries needs the sparse paged step "
                         "(refresh implies decode_sparse)")
-                def fn(params, token, cache, page_table, pos, plens,
-                       pflens):
+                def decode_step(params, token, cache, page_table, pos,
+                                plens, pflens):
                     return self.model.decode(
                         params, token, cache, pos, prompt_lens=plens,
                         prefill_len=pflens, page_table=page_table)
-            self._decode_cache[key] = jax.jit(fn)
+            self._decode_cache[key] = jax.jit(decode_step)
         return self._decode_cache[key]
 
     def _chunk_tokens(self, seq: int) -> int:

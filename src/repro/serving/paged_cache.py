@@ -58,6 +58,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed.sharding import shard
 from repro.kernels.decode_attn import gather_pages  # re-export  # noqa: F401
 from repro.serving.cache_ops import slice_segment
 
@@ -218,8 +219,11 @@ def init_paged_pool(cfg, *, num_pages: int, page_size: int,
         raise ValueError("paged KV cache covers the scanned stack only")
     hd = cfg.resolved_head_dim
     shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size, hd)
-    return {"prefix": [], "stack": (jnp.zeros(shape, dtype),
-                                    jnp.zeros(shape, dtype))}
+    # under a sharding-rules context the pool is born split along Hkv, the
+    # axis the sharded decode kernel partitions; unmeshed this is a no-op
+    k, v = (shard(jnp.zeros(shape, dtype), None, None, "kv_heads")
+            for _ in range(2))
+    return {"prefix": [], "stack": (k, v)}
 
 
 def _scatter_whole(pool, val, pages):

@@ -242,7 +242,8 @@ def test_shard_map_matches_single_device():
         m = (m | jnp.eye(NB, dtype=bool)[None, None]) \\
             & causal_block_mask(NB)[None, None]
 
-        mesh = jax.make_mesh((2,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("model",))
         assert head_shard_count(mesh, "model", H, HKV) == 2
         assert head_shard_count(mesh, "model", 3, HKV) == 1   # indivisible
         out_s, a_s = sharded_batched_block_sparse_attention(
@@ -261,13 +262,58 @@ def test_shard_map_matches_single_device():
         np.testing.assert_array_equal(np.asarray(out_f), np.asarray(out_1))
         print("SHARDED-OK")
     """)
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr
     assert "SHARDED-OK" in res.stdout
+
+
+@pytest.mark.subprocess
+def test_strip_kernel_under_mesh_matches_single_device():
+    """Under a serving mesh the strip kernel runs per head shard (a Mosaic
+    kernel cannot be partitioned by the compiler); heads that do not shard
+    take the oracle.  Both equal the single-device strips."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        import jax, numpy as np
+        import jax.numpy as jnp
+        from repro.distributed import sharding as dsh
+        from repro.kernels.strip import compute_strips
+        from repro.launch.mesh import make_mesh
+
+        H, HKV, N, D, BS = 4, 2, 256, 32, 64
+        ks = jax.random.split(jax.random.PRNGKey(7), 3)
+        q = jax.random.normal(ks[0], (2, H, N, D))
+        k = jax.random.normal(ks[1], (2, HKV, N, D))
+        strips = lambda q, k: jax.vmap(lambda a, b: compute_strips(
+            a, b, block_size=BS, impl="pallas"))(q, k)
+        want = strips(q, k)
+        calls = []
+        real = dsh.sharded_compute_strips
+        dsh.sharded_compute_strips = lambda *a, **kw: (
+            calls.append(1), real(*a, **kw))[1]
+        mesh = make_mesh((1, 2), ("data", "model"))
+        with dsh.use_rules(dsh.ShardingRules(mesh)), mesh:
+            got = jax.jit(strips)(q, k)
+            assert calls, "strip kernel did not route through shard_map"
+            odd = jax.jit(strips)(q[:, :3], k[:, :1])     # 3/1 heads
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_allclose(np.asarray(odd),
+                                   np.asarray(strips(q[:, :3], k[:, :1])),
+                                   rtol=1e-5, atol=1e-6)
+        print("STRIP-SHARDED-OK")
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr
+    assert "STRIP-SHARDED-OK" in res.stdout
 
 
 def test_decode_plan_kv_head_range_matches_global_slice():

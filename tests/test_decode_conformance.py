@@ -257,7 +257,9 @@ def test_kv_head_range_slices_match_global(case, impl):
 # --------------------------------------------------------------------------
 
 def _run_subprocess(code: str) -> subprocess.CompletedProcess:
-    env = dict(os.environ)
+    # the child stays on the CPU: a parent holding a chip would make a
+    # child that asks for it fail or hang
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep + TESTS
                          + os.pathsep + env.get("PYTHONPATH", ""))
     return subprocess.run([sys.executable, "-c", code], env=env,
@@ -278,7 +280,8 @@ def test_sharded_flash_decode_bitmatches_single_device():
         from repro.kernels.decode_attn import flash_decode_plan
         from test_decode_conformance import SHARDABLE, build_case
 
-        mesh = jax.make_mesh((2,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("model",))
         for case in SHARDABLE:
             assert head_shard_count(mesh, "model", case.h, case.hkv) == 2
             data = build_case(case)
@@ -357,7 +360,8 @@ def test_serving_engine_serve_under_mesh():
             reqs = [Request(uid=i, prompt=sample(dcfg, 7 + i)["tokens"],
                             max_new_tokens=5) for i in range(2)]
             if meshed:
-                mesh = jax.make_mesh((1, 2), ("data", "model"))
+                from repro.launch.mesh import make_mesh
+                mesh = make_mesh((1, 2), ("data", "model"))
                 with dsh.use_rules(dsh.ShardingRules(mesh)), mesh:
                     engine.serve(reqs)
             else:

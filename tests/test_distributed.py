@@ -13,11 +13,12 @@ from repro.distributed.param_specs import (
     param_pspecs,
 )
 from repro.distributed.sharding import ShardingRules, shard, use_rules
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_shard_noop_without_rules():
@@ -47,7 +48,7 @@ def test_leaf_pspec_rules():
 
 
 def test_leaf_pspec_divisibility_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     big_mesh_shape = {"data": 16, "model": 16}
 
     class FakeMesh:

@@ -95,7 +95,8 @@ def test_dryrun_pair_in_subprocess_8dev():
         from repro.training import TrainConfig, make_train_step
         from jax.sharding import NamedSharding
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_smoke_config("granite-3-2b")
         model = build_model(cfg)
         params_avals = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
@@ -122,7 +123,8 @@ def test_dryrun_pair_in_subprocess_8dev():
         assert cost.get("flops", 0) > 0
         print("SUBPROCESS_OK", int(cost.get("flops", 0)))
     """)
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=540)
     assert "SUBPROCESS_OK" in out.stdout, out.stderr[-2000:]

@@ -115,7 +115,8 @@ def test_moe_expert_fallback_sharding():
 def test_shard_dedupe_no_duplicate_axis():
     import jax
     from repro.distributed.sharding import ShardingRules, shard, use_rules
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     with use_rules(ShardingRules(mesh)):
         x = jnp.ones((4, 8, 16, 32))
         # batch→data and seq→data would collide; dedupe must keep batch only

@@ -399,7 +399,9 @@ def test_paged_chunked_admission_bitmatches(setup):
 # --------------------------------------------------------------------------
 
 def _run_subprocess(code: str) -> subprocess.CompletedProcess:
-    env = dict(os.environ)
+    # the child stays on the CPU: a parent holding a chip would make a
+    # child that asks for it fail or hang
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep + TESTS
                          + os.pathsep + env.get("PYTHONPATH", ""))
     return subprocess.run([sys.executable, "-c", code], env=env,
@@ -421,7 +423,8 @@ def test_sharded_paged_decode_bitmatches():
         from test_decode_conformance import SHARDABLE, build_case, _run
         from test_paged_cache import _page_in
 
-        mesh = jax.make_mesh((2,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("model",))
         for case in SHARDABLE:
             data = build_case(case)
             pk, pv, table = _page_in(data.cache_k, data.cache_v, case.bs)

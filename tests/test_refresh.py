@@ -290,7 +290,9 @@ def test_preempt_resume_rebuilds_refresh_state(setup):
 # --------------------------------------------------------------------------
 
 def _run_subprocess(code: str) -> subprocess.CompletedProcess:
-    env = dict(os.environ)
+    # the child stays on the CPU: a parent holding a chip would make a
+    # child that asks for it fail or hang
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep + TESTS
                          + os.pathsep + env.get("PYTHONPATH", ""))
     return subprocess.run([sys.executable, "-c", code], env=env,

@@ -365,7 +365,9 @@ def test_vector_pos_mla_raises():
 # --------------------------------------------------------------------------
 
 def _run_subprocess(code: str) -> subprocess.CompletedProcess:
-    env = dict(os.environ)
+    # the child stays on the CPU: a parent holding a chip would make a
+    # child that asks for it fail or hang
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep + TESTS
                          + os.pathsep + env.get("PYTHONPATH", ""))
     return subprocess.run([sys.executable, "-c", code], env=env,
@@ -420,7 +422,8 @@ def test_scheduler_serve_under_mesh_bitmatches():
                             max_new_tokens=m)
                     for i, m in enumerate((4, 2, 3))]
             if meshed:
-                mesh = jax.make_mesh((1, 2), ("data", "model"))
+                from repro.launch.mesh import make_mesh
+                mesh = make_mesh((1, 2), ("data", "model"))
                 with dsh.use_rules(dsh.ShardingRules(mesh)), mesh:
                     engine.serve(reqs)
             else:

@@ -1,0 +1,124 @@
+"""Chip-compiles of the serving path's Pallas kernels, with no chip attached.
+
+Each test lowers one kernel at internlm2-1.8b widths (16 query / 8 kv
+heads, head_dim 128, 8,192 tokens, block 64 and 128) for one chip of a
+described TPU v5e topology, and asserts that the compiled program carries
+the Mosaic kernel (``tpu_custom_call``).  The TPU compiler refuses here
+what interpret mode accepts: block shapes that break the (8, 128) tiling
+rule, scalar stores to VMEM, unsupported vector reshapes, and kernels
+that want more VMEM than the chip has.  Nothing runs, so these say
+nothing about results or times.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may load the TPU library, and the
+test workers import every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+H, HKV, D, N = 16, 8, 128, 8192
+CHUNK = 1024
+SLOTS = 8
+BLOCKS = (64, 128)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A sharding on the described chip; the persistent compilation cache
+    is off meanwhile (a chip compile written to it cannot be read back
+    without the chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("bs", BLOCKS)
+def test_prefill_full_launch(one_chip, bs):
+    from repro.kernels.block_sparse_attn import block_sparse_attention_batched
+    nb = N // bs
+    _compile(lambda q, k, v, i, c: block_sparse_attention_batched(
+                 q, k, v, i, c, block_size=bs, interpret=False),
+             [((1, H, N, D), jnp.bfloat16), ((1, HKV, N, D), jnp.bfloat16),
+              ((1, HKV, N, D), jnp.bfloat16), ((1, H, nb, nb), jnp.int32),
+              ((1, H, nb), jnp.int32)], one_chip)
+
+
+@pytest.mark.parametrize("bs", BLOCKS)
+def test_prefill_chunk_launch(one_chip, bs):
+    from repro.kernels.block_sparse_attn import block_sparse_attention_batched
+    nb, cq = N // bs, CHUNK // bs
+    _compile(lambda q, k, v, i, c: block_sparse_attention_batched(
+                 q, k, v, i, c, block_size=bs, q_block_offset=nb // 2,
+                 interpret=False),
+             [((1, H, CHUNK, D), jnp.bfloat16),
+              ((1, HKV, N, D), jnp.bfloat16), ((1, HKV, N, D), jnp.bfloat16),
+              ((1, H, cq, nb), jnp.int32), ((1, H, cq), jnp.int32)],
+             one_chip)
+
+
+@pytest.mark.parametrize("bs", BLOCKS)
+def test_prefill_paged_chunk_launch(one_chip, bs):
+    from repro.kernels.block_sparse_attn import (
+        block_sparse_attention_batched_paged)
+    nb, cq = N // bs, CHUNK // bs
+    pages = nb + 1
+    _compile(lambda q, pk, pv, pt, i, c: block_sparse_attention_batched_paged(
+                 q, pk, pv, pt, i, c, block_size=bs, q_block_offset=nb // 2,
+                 interpret=False),
+             [((1, H, CHUNK, D), jnp.bfloat16),
+              ((pages, HKV, bs, D), jnp.bfloat16),
+              ((pages, HKV, bs, D), jnp.bfloat16), ((1, nb), jnp.int32),
+              ((1, H, cq, nb), jnp.int32), ((1, H, cq), jnp.int32)],
+             one_chip)
+
+
+@pytest.mark.parametrize("bs", BLOCKS)
+def test_strip(one_chip, bs):
+    from repro.kernels.strip import strip_scores_pallas
+    _compile(lambda q, k: strip_scores_pallas(q, k, block_size=bs,
+                                              interpret=False),
+             [((H, N, D), jnp.bfloat16), ((HKV, N, D), jnp.bfloat16)],
+             one_chip)
+
+
+@pytest.mark.parametrize("bs", BLOCKS)
+def test_paged_decode(one_chip, bs):
+    from repro.kernels.decode_attn import flash_decode_sparse_batched_paged
+    nb = N // bs
+    pages = SLOTS * nb + 1
+    _compile(lambda q, pk, pv, pt, i, c, kh, va:
+             flash_decode_sparse_batched_paged(q, pk, pv, pt, i, c, kh, va,
+                                               interpret=False),
+             [((SLOTS, H, D), jnp.bfloat16),
+              ((pages, HKV, bs, D), jnp.bfloat16),
+              ((pages, HKV, bs, D), jnp.bfloat16),
+              ((SLOTS, nb), jnp.int32), ((SLOTS, HKV, nb), jnp.int32),
+              ((SLOTS, HKV), jnp.int32),
+              ((SLOTS, HKV, nb, H // HKV), jnp.bool_),
+              ((SLOTS, N), jnp.bool_)], one_chip)
