@@ -47,6 +47,12 @@ window, collapsing the grown dense tail to a bounded horizon under
 per-head score-mass budgets (``--refresh-mass``).  Refresh trades the
 frozen-plan bitwise guarantee for measured decode-traffic reduction;
 with the default 0 the serve is bitwise-identical to the frozen path.
+
+``--profile-dir DIR`` writes a profiler trace of the serve under DIR
+(``jax.profiler.trace``): the scheduler's spans (``repro.serving.tracing``:
+one ``sched_step`` per scheduler step, ``prefill_quantum``, ``kv_insert``,
+``decode_step`` and its host phases, ...) on the host plane, beside the
+device's program runs.  Open it in TensorBoard or Perfetto.
 """
 from __future__ import annotations
 
@@ -144,6 +150,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="model-axis size of the serving mesh; > 1 runs "
                     "prefill and decode heads-sharded under shard_map")
     ap.add_argument("--task", default="retrieval")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a profiler trace of the serve (host spans "
+                    "and device runs) under this directory")
     return ap.parse_args(argv)
 
 
@@ -209,6 +218,8 @@ def serve(args: argparse.Namespace):
         ctx.enter_context(use_rules(ShardingRules(mesh)))
         ctx.enter_context(mesh)
         print(f"serving under mesh {dict(mesh.shape)}")
+    if args.profile_dir:
+        ctx.enter_context(jax.profiler.trace(args.profile_dir))
 
     with ctx:
         t0 = time.time()
@@ -274,6 +285,10 @@ def report(args: argparse.Namespace, engine: ServingEngine, requests,
     print(f"total wall {wall:.2f}s, method={args.method}, mode={mode}, "
           f"slot occupancy {engine.slot_occupancy():.3f}, "
           f"phase_s={ {k: round(v, 3) for k, v in engine.phase_s.items()} }")
+    slow = engine.slowest_step
+    if slow["step"]:
+        print(f"slowest scheduler step: #{slow['step']} {slow['s']:.3f}s, "
+              f"mostly {slow['phase']}")
 
 
 def main(argv=None) -> int:

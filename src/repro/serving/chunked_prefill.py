@@ -88,7 +88,7 @@ class ChunkedPrefillRun:
         self.x = None
         self.layer = 0
         self._phase = "begin"
-        self._chunk_i = 0
+        self.chunk_i = 0
         self._q = self._k = self._v = None
         self._masks = self._decision = self._gate = self._perm = None
         self._outs: List = []
@@ -104,6 +104,13 @@ class ChunkedPrefillRun:
     @property
     def done(self) -> bool:
         return self._phase == "done"
+
+    @property
+    def quantum(self) -> str:
+        """The next quantum's kind, named after the program it runs:
+        ``begin``, ``layer_begin``, ``attn`` (chunk ``chunk_i`` of layer
+        ``layer``), ``layer_end`` or ``finish``."""
+        return "attn" if self._phase == "chunk" else self._phase
 
     def abort(self) -> None:
         """Abandon the run between quanta (cancellation / deadline /
@@ -140,11 +147,11 @@ class ChunkedPrefillRun:
                  self.cluster_arr)
             jax.block_until_ready(self._q)
             self._outs, self._ats = [], []
-            self._chunk_i = 0
+            self.chunk_i = 0
             self._phase = "chunk"
 
         elif self._phase == "chunk":
-            cs, cb = self.chunks[self._chunk_i]
+            cs, cb = self.chunks[self.chunk_i]
             out, at = self.fns["attn"](
                 self._q, self._k, self._v, self._masks, self._gate,
                 self._perm, chunk_start=cs, chunk_blocks=cb)
@@ -152,8 +159,8 @@ class ChunkedPrefillRun:
             self._outs.append(out)
             if at is not None:
                 self._ats.append(at)
-            self._chunk_i += 1
-            if self._chunk_i == len(self.chunks):
+            self.chunk_i += 1
+            if self.chunk_i == len(self.chunks):
                 self._phase = "layer_end"
 
         elif self._phase == "layer_end":

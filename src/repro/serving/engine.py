@@ -394,6 +394,11 @@ class ServingEngine:
         # interference measurable instead of inferred
         self.phase_s: Dict[str, float] = {"prefill": 0.0, "decode": 0.0,
                                           "idle": 0.0, "refresh": 0.0}
+        # the slowest scheduler step of the serve: its seconds, step
+        # number, the phase_s key that took most of it and its wall by
+        # phase_s key (tracing.step)
+        self.slowest_step: Dict[str, Any] = {"s": 0.0, "step": 0,
+                                             "phase": "", "phase_s": {}}
         # paged-cache accounting, reset per serve(): admissions deferred on
         # pool headroom, and the pool's capacity/peak/utilization summary
         # (filled by the paged scheduler)
@@ -770,6 +775,8 @@ class ServingEngine:
         self.active_slot_steps = 0
         self.phase_s = {"prefill": 0.0, "decode": 0.0, "idle": 0.0,
                         "refresh": 0.0}
+        self.slowest_step = {"s": 0.0, "step": 0, "phase": "",
+                             "phase_s": {}}
         self.pages_exhausted_steps = 0
         self.page_pool_stats = {}
         self.prefix_stats = {}

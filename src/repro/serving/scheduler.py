@@ -97,10 +97,13 @@ The scheduler reuses the engine's compiled-program caches (prefill at
 batch 1 or the chunk-quantum cache; the decode program retraces once for
 vector ``pos``), its width policies, and its slot-occupancy accounting.
 Admission interference is *measured*, not inferred: every prefill quantum
-(or one-shot launch) adds to ``engine.phase_s["prefill"]`` — decode steps
-and idle sleeps likewise — and wall time a request's admission spent while
-≥ 1 slot was occupied lands in that request's ``prefill_stall_s`` (split
-across a packed run's segments).
+(or one-shot launch), K/V insert and run completion adds to
+``engine.phase_s["prefill"]`` — decode steps and idle sleeps likewise —
+and wall time a request's admission spent while ≥ 1 slot was occupied
+lands in that request's ``prefill_stall_s`` (split across a packed run's
+segments).  Each of those is a named span of ``repro.serving.tracing``
+(one ``sched_step`` per scheduler step), so a profiler trace puts the
+device's program runs and idle gaps down to the host work behind them.
 
 Arrival simulation: requests carry ``arrival_s`` offsets (relative to
 ``serve()`` start); a request is admitted only once its arrival time has
@@ -234,6 +237,7 @@ from repro.serving import paged_cache
 from repro.serving import prefix_cache
 from repro.serving import refresh as refresh_mod
 from repro.serving import sparse_decode
+from repro.serving import tracing
 from repro.serving.chunked_prefill import ChunkedPrefillRun
 from repro.serving.errors import RequestError
 from repro.serving.sampling import sample_token
@@ -440,11 +444,12 @@ class SlotScheduler:
                 self._run_chunked()
             else:
                 while self.queue or any(s is not None for s in self.slots):
-                    self._step_begin()
-                    self._admit()
-                    self._flush_stale_slots()
-                    if any(s is not None for s in self.slots):
-                        self._decode_step()
+                    with self._sched_step():
+                        self._step_begin()
+                        self._admit()
+                        self._flush_stale_slots()
+                        if any(s is not None for s in self.slots):
+                            self._decode_step()
                 self._flush_stale_slots()   # leave the documented
                                             # invariant: unoccupied slots'
                                             # tables are empty
@@ -464,33 +469,43 @@ class SlotScheduler:
         the fair-share cadence that bounds admission stall per step."""
         while (self.queue or self.run_ is not None
                or any(s is not None for s in self.slots)):
-            self._step_begin()
-            self._prefill_step()
-            if (self.run_ is not None and self.paged and self.queue
-                    and (self.t0 + self.queue[0].arrival_s) <= time.time()
-                    and self._prefix_entry(self.queue[0]) is None):
-                self._shed_index_for(self.queue[0])
-                if (self.alloc.free_pages
-                        < self._pages_needed(self.queue[0])):
-                    # the queue head would be starved even once the
-                    # in-flight run lands — keep the starvation clock
-                    # ticking so a decoding victim can be evicted
-                    # mid-chunked-admission
-                    self._note_starved(self.queue[0])
-            self._flush_stale_slots()
-            if any(s is not None for s in self.slots):
-                self._decode_step()
+            with self._sched_step():
+                self._step_begin()
+                self._prefill_step()
+                if (self.run_ is not None and self.paged and self.queue
+                        and (self.t0 + self.queue[0].arrival_s)
+                        <= time.time()
+                        and self._prefix_entry(self.queue[0]) is None):
+                    self._shed_index_for(self.queue[0])
+                    if (self.alloc.free_pages
+                            < self._pages_needed(self.queue[0])):
+                        # the queue head would be starved even once the
+                        # in-flight run lands — keep the starvation clock
+                        # ticking so a decoding victim can be evicted
+                        # mid-chunked-admission
+                        self._note_starved(self.queue[0])
+                self._flush_stale_slots()
+                if any(s is not None for s in self.slots):
+                    self._decode_step()
         self._flush_stale_slots()
 
-    def _step_begin(self) -> None:
-        """Per-step lifecycle tick: advance the step counter, let the
-        fault injector act (due cancels, page-exhaustion windows), then
-        reap cancelled / deadline-expired requests."""
+    def _sched_step(self) -> tracing.step:
+        """Advance the step counter; the step runs inside the returned
+        ``sched_step`` span (queue length and free pages at its start)."""
         self.step_i += 1
+        return tracing.step(self.eng, self.step_i, waiting=len(self.queue),
+                            free_pages=self.alloc.free_pages
+                            if self.paged else 0)
+
+    def _step_begin(self) -> None:
+        """Per-step lifecycle tick: let the fault injector act (due
+        cancels, page-exhaustion windows), then reap cancelled /
+        deadline-expired requests."""
         if self.faults is not None:
             self.faults.on_step(self.step_i,
                                 alloc=self.alloc if self.paged else None)
-        self._reap()
+        with tracing.span("reap"):
+            self._reap()
 
     def _reap(self) -> None:
         """Terminate cancelled / deadline-expired requests wherever they
@@ -979,30 +994,30 @@ class SlotScheduler:
         alloc_blocks = len(self.slot_pages.get(slot, ()))
         if nblk <= 0 or not alloc_blocks:
             return
-        t0 = time.time()
-        horizon = max(min(self.horizon_blocks, alloc_blocks - nblk), 0)
-        row = dplan.build_refresh_plan_row(
-            jnp.asarray(st.window()), self.cache["stack"][0],
-            jnp.asarray(self.page_table[slot]), eng.model.cfg,
-            block_size=bs, num_blocks=nblk,
-            table_blocks=self.table_blocks, horizon_blocks=horizon,
-            mass=ecfg.refresh_mass, min_width=ecfg.refresh_min_width,
-            strip_impl=ecfg.refresh_strip_impl)
-        self._slot_rows[slot] = row
-        self._splice_row(slot, row)
-        st.last_refresh_pos = pos
-        st.horizon_end = nblk + horizon
-        r = s.req
-        r.refreshes += 1
-        eng.refresh_stats["refreshes"] += 1
-        r.tail_fraction, r.plan_traffic_fraction = \
-            dplan.plan_row_tail_stats(
-                row, prefill_blocks=int(self.pflens[slot]) // bs,
-                num_blocks=alloc_blocks)
-        if r.pattern_stats is not None:
-            r.pattern_stats["decode_traffic_fraction"] = \
-                r.plan_traffic_fraction
-        eng.phase_s["refresh"] += time.time() - t0
+        with tracing.phase(eng.phase_s, "refresh", "refresh",
+                           uid=s.req.uid):
+            horizon = max(min(self.horizon_blocks, alloc_blocks - nblk), 0)
+            row = dplan.build_refresh_plan_row(
+                jnp.asarray(st.window()), self.cache["stack"][0],
+                jnp.asarray(self.page_table[slot]), eng.model.cfg,
+                block_size=bs, num_blocks=nblk,
+                table_blocks=self.table_blocks, horizon_blocks=horizon,
+                mass=ecfg.refresh_mass, min_width=ecfg.refresh_min_width,
+                strip_impl=ecfg.refresh_strip_impl)
+            self._slot_rows[slot] = row
+            self._splice_row(slot, row)
+            st.last_refresh_pos = pos
+            st.horizon_end = nblk + horizon
+            r = s.req
+            r.refreshes += 1
+            eng.refresh_stats["refreshes"] += 1
+            r.tail_fraction, r.plan_traffic_fraction = \
+                dplan.plan_row_tail_stats(
+                    row, prefill_blocks=int(self.pflens[slot]) // bs,
+                    num_blocks=alloc_blocks)
+            if r.pattern_stats is not None:
+                r.pattern_stats["decode_traffic_fraction"] = \
+                    r.plan_traffic_fraction
 
     def _admit(self) -> None:
         """WAITING → PREFILL: fill free slots from the arrival queue."""
@@ -1027,10 +1042,15 @@ class SlotScheduler:
             if wait > 0:
                 if any(s is not None for s in self.slots):
                     return              # keep decoding, admit it later
-                time.sleep(wait)        # fully idle: jump to next arrival
-                self.eng.phase_s["idle"] += wait
+                self._idle_sleep(wait)  # fully idle: jump to next arrival
             self.queue.popleft()
             self._start(r, free[0])
+
+    def _idle_sleep(self, wait: float) -> None:
+        """Every slot idle and the queue head not yet due: sleep until it
+        arrives."""
+        with tracing.phase(self.eng.phase_s, "idle", "idle_sleep"):
+            time.sleep(wait)
 
     def _start(self, r, slot: int) -> None:
         """PREFILL → DECODE: prefill one request alone (one-shot), sample
@@ -1049,30 +1069,31 @@ class SlotScheduler:
         plen = eng._pad_prompt(r, seq, toks[0])
 
         width = eng._width_cap(seq)
-        tp = time.time()
-        r.queue_s = max(tp - (self.t0 + r.arrival_s), 0.0)
+        r.queue_s = max(time.time() - (self.t0 + r.arrival_s), 0.0)
+        launch = tracing.phase(eng.phase_s, "prefill", "prefill_launch",
+                               uid=r.uid)
         try:
             # per-request prefill quarantine: an exception (or injected
             # fault) fails ONLY this request — no slot was occupied and no
             # pages granted yet, so nothing to unwind
-            if self.faults is not None:
-                self.faults.check_prefill([r.uid])
-            prefill = eng._prefill_fn(1, seq, width)
-            result = prefill(eng.params, jnp.asarray(toks),
-                             jnp.asarray([plen], jnp.int32))
-            jax.block_until_ready(result.last_logits)
-            finite = bool(np.isfinite(np.asarray(result.last_logits)).all())
+            with launch:
+                if self.faults is not None:
+                    self.faults.check_prefill([r.uid])
+                prefill = eng._prefill_fn(1, seq, width)
+                result = prefill(eng.params, jnp.asarray(toks),
+                                 jnp.asarray([plen], jnp.int32))
+                jax.block_until_ready(result.last_logits)
+                finite = bool(
+                    np.isfinite(np.asarray(result.last_logits)).all())
         except Exception as e:          # noqa: BLE001 — quarantine wall
-            r.prefill_s = time.time() - tp
-            eng.phase_s["prefill"] += r.prefill_s
+            r.prefill_s = launch.s
             err = (e if isinstance(e, RequestError) else RequestError(
                 r.uid, f"prefill raised {type(e).__name__}: {e}",
                 kind="prefill"))
             logger.warning("quarantined: %s", err)
             self._finish_inert(r, "failed", error=err)
             return
-        r.prefill_s = time.time() - tp
-        eng.phase_s["prefill"] += r.prefill_s
+        r.prefill_s = launch.s
         if any(s is not None for s in self.slots):
             # the whole-sequence launch ran while other slots wanted to
             # decode — the interference chunked admission amortizes
@@ -1196,13 +1217,14 @@ class SlotScheduler:
         # the hit never reaches _pad_prompt, so flag the clip here — the
         # digest already hashed the clipped tokens (that IS the hit)
         r.truncated = len(np.asarray(r.prompt)) > seq
-        tp = time.time()
-        r.queue_s = max(tp - (self.t0 + r.arrival_s), 0.0)
+        r.queue_s = max(time.time() - (self.t0 + r.arrival_s), 0.0)
+        hit = tracing.phase(eng.phase_s, "prefill", "prefix_hit", uid=r.uid)
         try:
             # injected prefill faults still apply: a poisoned request
             # fails deterministically whether or not its prompt is cached
-            if self.faults is not None:
-                self.faults.check_prefill([r.uid])
+            with hit:
+                if self.faults is not None:
+                    self.faults.check_prefill([r.uid])
         except Exception as e:          # noqa: BLE001 — quarantine wall
             err = (e if isinstance(e, RequestError) else RequestError(
                 r.uid, f"prefill raised {type(e).__name__}: {e}",
@@ -1210,8 +1232,7 @@ class SlotScheduler:
             logger.warning("quarantined: %s", err)
             self._finish_inert(r, "failed", error=err)
             return
-        r.prefill_s = time.time() - tp  # ≈ 0: the hit skips the launch
-        eng.phase_s["prefill"] += r.prefill_s
+        r.prefill_s = hit.s             # ≈ 0: the hit skips the launch
         r.prefix_hit = True
         entry.hits += 1
         self.prefix.hits += 1
@@ -1311,8 +1332,7 @@ class SlotScheduler:
         if wait > 0:
             if any(s is not None for s in self.slots):
                 return None             # keep decoding, admit it later
-            time.sleep(wait)            # fully idle: jump to next arrival
-            eng.phase_s["idle"] += wait
+            self._idle_sleep(wait)      # fully idle: jump to next arrival
         if head_hit:
             # a prefix-cache hit needs no chunked run at all — _start
             # routes it through the hit path (cached logits + mapped
@@ -1370,29 +1390,34 @@ class SlotScheduler:
         first if none is in flight): the chunked loop's prefill share of
         each scheduler step."""
         if self.run_ is None:
-            self.run_ = self._assemble_run()
+            with tracing.span("assemble_run"):
+                self.run_ = self._assemble_run()
             if self.run_ is None:
                 return
         run = self.run_
+        phase_s = self.eng.phase_s
+        uid = tracing.uid_arg(run.requests)
         occupied = any(s is not None for s in self.slots)
-        tq = time.time()
+        quantum = tracing.phase(phase_s, "prefill", "prefill_quantum",
+                                kind=run.quantum, layer=run.layer,
+                                chunk=run.chunk_i, uid=uid)
         try:
-            if self.faults is not None:
-                # injected prefill faults land between quanta: a raised
-                # PrefillError quarantines the run; a SlowQuantum delay
-                # stretches the quantum so deadlines can expire it
-                self.faults.check_prefill([r.uid for r in run.requests])
-                d = self.faults.quantum_delay([r.uid for r in run.requests])
-                if d > 0:
-                    time.sleep(d)
-            ev = run.step()
+            with quantum:
+                if self.faults is not None:
+                    # injected prefill faults land between quanta: a raised
+                    # PrefillError quarantines the run; a SlowQuantum delay
+                    # stretches the quantum so deadlines can expire it
+                    self.faults.check_prefill([r.uid for r in run.requests])
+                    d = self.faults.quantum_delay(
+                        [r.uid for r in run.requests])
+                    if d > 0:
+                        time.sleep(d)
+                ev = run.step()
         except Exception as e:          # noqa: BLE001 — quarantine wall
-            self.eng.phase_s["prefill"] += time.time() - tq
             self._quarantine_run(run, e)
             return
-        dt = time.time() - tq
+        dt = quantum.s
         self._run_wall += dt
-        self.eng.phase_s["prefill"] += dt
         if occupied:
             # this quantum ran instead of a decode step: charge the stall
             # to the admitting request(s), split across packed segments
@@ -1400,9 +1425,14 @@ class SlotScheduler:
             for r in run.requests:
                 r.prefill_stall_s += share
         if ev == "kv":
-            self._insert_kv(run)
+            pages = (run.P * run.seq // self.page_size if self.paged
+                     else 0)
+            with tracing.phase(phase_s, "prefill", "kv_insert",
+                               layer=run.kv_layer, pages=pages, uid=uid):
+                self._insert_kv(run)
         elif ev == "done":
-            self._complete_run(run)
+            with tracing.phase(phase_s, "prefill", "complete_run", uid=uid):
+                self._complete_run(run)
             self.run_ = None
 
     def _insert_kv(self, run: ChunkedPrefillRun) -> None:
@@ -1559,13 +1589,27 @@ class SlotScheduler:
         """One fixed-shape decode step over all slots (occupied or inert),
         then per-slot sampling, early exit, and slot freeing."""
         eng = self.eng
-        td = time.time()
+        if self.prefix is not None or self.refresh_on:
+            with tracing.phase(eng.phase_s, "decode", "decode_guard"):
+                self._decode_guard()
+        occ = [i for i, s in enumerate(self.slots) if s is not None]
+        eng.slot_steps += self.nslots
+        eng.active_slot_steps += len(occ)
+        with tracing.phase(eng.phase_s, "decode", "decode_step",
+                           occupied=len(occ)):
+            self._decode_occupied(occ)
+        if self.refresh_on:
+            self._refresh_tick()
+
+    def _decode_guard(self) -> None:
+        """Make every occupied slot safe to append before the step."""
         if self.prefix is not None:
             # COW guard at the decode boundary: every occupied slot about
             # to append into a shared page is moved onto a fresh private
             # copy first (or, on true pool exhaustion, preempted) — a
-            # shared page is never written.  Runs before ``occ`` is
-            # computed so a COW-preempted slot sits this step out.
+            # shared page is never written.  Runs before the step's
+            # occupied slots are listed, so a COW-preempted slot sits this
+            # step out.
             for i, s in enumerate(self.slots):
                 if s is not None:
                     self._cow_append_page(i)
@@ -1574,43 +1618,55 @@ class SlotScheduler:
             # step's append block — extend it (cheaply, no strip pass)
             # before the kernels run
             self._horizon_guard()
-        occ = [i for i, s in enumerate(self.slots) if s is not None]
-        eng.slot_steps += self.nslots
-        eng.active_slot_steps += len(occ)
 
-        toks = np.zeros((self.nslots,), np.int32)
-        for i in occ:
-            toks[i] = self.slots[i].last_tok
-        if self.paged:
-            decode = eng._decode_fn_paged(self.nslots, self.table_blocks,
-                                          self.use_sparse,
-                                          collect_queries=self.refresh_on)
-            args = (eng.params, jnp.asarray(toks)[:, None], self.cache,
-                    jnp.asarray(self.page_table), jnp.asarray(self.pos),
-                    jnp.asarray(self.plens), jnp.asarray(self.pflens))
-        else:
-            decode = eng._decode_fn(self.nslots, self.seq, self.cache_len,
-                                    self.use_sparse)
-            args = (eng.params, jnp.asarray(toks)[:, None], self.cache,
-                    jnp.asarray(self.pos), jnp.asarray(self.plens))
-        qs = None
-        if self.refresh_on:
-            logits, self.cache, qs = decode(*args, self.plan)
-        elif self.use_sparse:
-            logits, self.cache = decode(*args, self.plan)
-        else:
-            logits, self.cache = decode(*args)
+    def _decode_occupied(self, occ: List[int]) -> None:
+        """Launch the step for slots ``occ`` (the others decode inert),
+        pull its logits, and sample each occupied slot's next token."""
+        eng = self.eng
+        with tracing.span("decode_args"):
+            toks = np.zeros((self.nslots,), np.int32)
+            for i in occ:
+                toks[i] = self.slots[i].last_tok
+            if self.paged:
+                decode = eng._decode_fn_paged(
+                    self.nslots, self.table_blocks, self.use_sparse,
+                    collect_queries=self.refresh_on)
+                args = (eng.params, jnp.asarray(toks)[:, None], self.cache,
+                        jnp.asarray(self.page_table), jnp.asarray(self.pos),
+                        jnp.asarray(self.plens), jnp.asarray(self.pflens))
+            else:
+                decode = eng._decode_fn(self.nslots, self.seq,
+                                        self.cache_len, self.use_sparse)
+                args = (eng.params, jnp.asarray(toks)[:, None], self.cache,
+                        jnp.asarray(self.pos), jnp.asarray(self.plens))
+        with tracing.span("decode_launch"):
+            qs = None
+            if self.refresh_on:
+                logits, self.cache, qs = decode(*args, self.plan)
+            elif self.use_sparse:
+                logits, self.cache = decode(*args, self.plan)
+            else:
+                logits, self.cache = decode(*args)
 
         # one device→host sync for the whole step: greedy rows (the
         # conformance-critical common case) take np.argmax on the pulled
         # logits — same first-max-index rule as jnp.argmax, so tokens stay
         # bitwise equal to the legacy path — and only temperature-sampled
         # rows pay a per-slot device dispatch
-        logits_h = np.asarray(logits)
-        if qs is not None:
+        with tracing.span("decode_wait"):
+            logits_h = np.asarray(logits)
+            qs_h = None if qs is None else np.asarray(qs)
+        with tracing.span("sample"):
+            self._sample(occ, logits, logits_h, qs_h)
+
+    def _sample(self, occ: List[int], logits, logits_h: np.ndarray,
+                qs_h: Optional[np.ndarray]) -> None:
+        """Per occupied slot: record its query (refresh), quarantine a
+        non-finite row, take its next token, and vacate it at a stop token
+        or its length."""
+        if qs_h is not None:
             # ring up this step's post-rope queries (positions == current
             # self.pos, pre-increment) into each occupied slot's window
-            qs_h = np.asarray(qs)
             for i in occ:
                 st = self.refresh.get(i)
                 if st is not None:
@@ -1650,9 +1706,6 @@ class SlotScheduler:
                 self._vacate(i, s, "stop")
             elif len(s.outs) >= s.req.max_new_tokens:
                 self._vacate(i, s, "length")
-        eng.phase_s["decode"] += time.time() - td
-        if self.refresh_on:
-            self._refresh_tick()
 
     def _vacate(self, slot: int, s: _Slot, reason: str) -> None:
         """Free a slot mid-decode: the request finalizes and the slot's
