@@ -37,7 +37,7 @@ from repro.distributed.sharding import (
 from repro.kernels import batched_sparse_attention_fn, sparse_attention_fn
 from repro.kernels.chunked import chunked_attention, chunked_attention_fn
 from repro.kernels.decode_attn import (DecodePlan, flash_decode_plan,
-                                       flash_decode_plan_paged, gather_pages)
+                                       flash_decode_plan_paged)
 from repro.kernels.indices import cap_block_mask
 from repro.kernels.ops import make_attention_fn
 from repro.kernels.ref import decode_attention_ref
@@ -275,6 +275,8 @@ def attention_decode(
     plan: Optional[DecodePlan] = None,  # one layer's sparse-decode tables
     decode_impl: str = "auto",          # auto | kernel | einsum
     page_table: Optional[jnp.ndarray] = None,   # (B, NB) block-paged cache
+    pool_layer: Optional[jnp.ndarray] = None,   # this layer's index into
+                                        # whole (L, P, Hkv, ps, hd) pools
     return_q: bool = False,             # also return this step's (B, H, hd)
                                         # post-rope query vectors
 ) -> Tuple[jnp.ndarray, ...]:
@@ -294,11 +296,13 @@ def attention_decode(
 
     ``page_table`` switches the cache contract to the block-paged pool:
     ``cache_k``/``cache_v`` are then one layer's shared page-pool slice
-    ``(P, Hkv, page_size, hd)`` and the table maps each slot's logical
-    block to its page.  The token append becomes a single-sliver in-place
-    scatter through the table (no whole-row copies), and attention walks
-    the pool via the page-aware kernel twins.  Paged decode is a
-    continuous-batching contract: ``pos`` must be the per-slot vector.
+    ``(P, Hkv, page_size, hd)`` — or, with ``pool_layer``, the whole
+    ``(L, P, Hkv, page_size, hd)`` pools the dense decode loop carries,
+    addressed at that layer — and the table maps each slot's logical
+    block to its page.  The token append rewrites each slot's current
+    page (no whole-row copies), and attention walks the pool via the
+    page-aware kernel twins.  Paged decode is a continuous-batching
+    contract: ``pos`` must be the per-slot vector.
 
     ``return_q`` appends this step's post-rope query vectors ``(B, H,
     hd)`` to the return tuple — the observable the decode-time pattern
@@ -316,7 +320,7 @@ def attention_decode(
         return ret(*_attention_decode_paged(
             params, cfg, q, k, v, cache_k, cache_v, pos, page_table,
             window=window, sink=sink, valid_mask=valid_mask, plan=plan,
-            decode_impl=decode_impl))
+            decode_impl=decode_impl, layer=pool_layer))
 
     s = cache_k.shape[2]
     if jnp.ndim(pos):                   # per-slot positions: per-row writes
@@ -350,9 +354,7 @@ def attention_decode(
                        | (pos_idx < sink))
         mask = jnp.broadcast_to(mask, (b, s))
 
-    g = cfg.gqa_groups
     hkv = cache_k.shape[1]
-    hd = q.shape[-1]
 
     if plan is not None:
         # decode-phase pattern sharing (beyond paper): stream only the
@@ -375,52 +377,57 @@ def attention_decode(
         out = out[:, :, None, :]                  # (B, H, 1, hd)
         return ret(common.gqa_out(params, out), (cache_k, cache_v))
 
-    # Dense decode WITHOUT materializing the expanded cache (§Perf iter 3):
-    # fold query heads into (kv_head, group) and contract against the
-    # grouped cache directly — HBM traffic is the cache once, not ×groups —
-    # and accumulate in f32 via preferred_element_type instead of casting
-    # the cache (an f32 cache copy would be hoisted to full stacked shape).
-    qg = q.squeeze(2).reshape(b, hkv, g, hd)
-    scale = 1.0 / (hd ** 0.5)
-    logits = jnp.einsum("bkgd,bksd->bkgs", qg, cache_k,
-                        preferred_element_type=jnp.float32) * scale
-    logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bksd->bkgd", jnp.asarray(p, cache_v.dtype),
-                     cache_v, preferred_element_type=jnp.float32)
-    out = jnp.asarray(out, x.dtype).reshape(b, hkv * g, 1, hd)
+    out = dense_decode(q.squeeze(2), cache_k, cache_v, mask)
+    out = jnp.asarray(out, x.dtype)[:, :, None, :]      # (B, H, 1, hd)
     return ret(common.gqa_out(params, out), (cache_k, cache_v))
 
 
 def _attention_decode_paged(params, cfg, q, k, v, pool_k, pool_v, pos,
                             page_table, *, window, sink, valid_mask, plan,
-                            decode_impl):
+                            decode_impl, layer=None):
     """Block-paged half of :func:`attention_decode` (post-QKV/rope).
 
-    The append is an in-place sliver scatter: the slot's current logical
-    block resolves to a page via the table and the token's ``(Hkv, hd)``
-    K/V lands at ``pos % page_size`` inside it — nothing else in the pool
-    is touched, so slots are bitwise independent.  Attention then walks
-    the pool through the page-aware kernel twins (or the gathered
-    contiguous view for dense decode), with all masks/tables kept in
-    *logical* slot coordinates over the virtual length ``NB·page_size``.
+    The pools are one layer's ``(P, Hkv, ps, hd)`` slice, or with
+    ``layer`` the whole ``(L, P, Hkv, ps, hd)`` pools that the dense
+    decode loop carries (and the engine donates), so the step updates
+    them in place.  The append is written per page: each slot's current
+    page — its logical block resolved through the table — is read, the
+    token's ``(Hkv, hd)`` K/V set at ``pos % page_size`` inside it, and
+    the whole page written back.  A page write keeps the pool's storage
+    layout, where a single-sliver scatter into the carried pool makes
+    XLA relayout all of it.  Only the slot's own current page changes
+    (inert slots: the null page or their frozen tail), so slots are
+    bitwise independent; inert slots that share the null page may
+    overwrite each other's page write there, and nothing reads the null
+    page unmasked.  Attention then walks the pool through the
+    page-aware kernel twins (sparse plan), or reads the resident pages
+    as ``(B, NB, Hkv, ps, hd)`` and contracts over them (dense), with
+    all masks/tables kept in *logical* slot coordinates over the virtual
+    length ``NB·page_size``.
     """
     b = q.shape[0]
-    ps = pool_k.shape[2]
+    ps = pool_k.shape[-2]
     sv = page_table.shape[1] * ps
     if not jnp.ndim(pos):
         raise ValueError("paged decode requires per-slot (vector) pos")
     rows = jnp.arange(b)
-    pg = page_table[rows, pos // ps]
+    lead = () if layer is None else (layer,)
+    cur = lead + (page_table[rows, pos // ps],)     # each slot's page
     within = pos % ps
-    pool_k = pool_k.at[pg, :, within, :].set(
-        k[:, :, 0, :].astype(pool_k.dtype))
-    pool_v = pool_v.at[pg, :, within, :].set(
-        v[:, :, 0, :].astype(pool_v.dtype))
-    # pool layout (P, Hkv, ps, hd): heads axis shards exactly like the
+
+    def append(pool, new):
+        page = pool[cur]                            # (B, Hkv, ps, hd)
+        page = page.at[rows, :, within].set(
+            new[:, :, 0, :].astype(pool.dtype))
+        return pool.at[cur].set(page)
+
+    pool_k = append(pool_k, k)
+    pool_v = append(pool_v, v)
+    # pool layout ([L,] P, Hkv, ps, hd): heads axis shards exactly like the
     # contiguous cache's; pages replicate across the batch by construction
-    pool_k = shard(pool_k, None, "kv_heads", None, "heads")
-    pool_v = shard(pool_v, None, "kv_heads", None, "heads")
+    axes = (None,) * len(lead) + (None, "kv_heads", None, "heads")
+    pool_k = shard(pool_k, *axes)
+    pool_v = shard(pool_v, *axes)
 
     pcol = pos[:, None]
     if valid_mask is None:
@@ -433,12 +440,8 @@ def _attention_decode_paged(params, cfg, q, k, v, pool_k, pool_v, pos,
                        | (pos_idx < sink))
         mask = jnp.broadcast_to(mask, (b, sv))
 
-    g = cfg.gqa_groups
-    hkv = pool_k.shape[1]
-    hd = q.shape[-1]
-
     if plan is not None:
-        mesh = shardable_model_mesh(q.shape[1], hkv)
+        mesh = shardable_model_mesh(q.shape[1], pool_k.shape[1])
         if mesh is not None:
             out = sharded_flash_decode_paged(
                 q.squeeze(2), pool_k, pool_v, page_table, plan, mask,
@@ -450,17 +453,43 @@ def _attention_decode_paged(params, cfg, q, k, v, pool_k, pool_v, pos,
         out = out[:, :, None, :]                  # (B, H, 1, hd)
         return common.gqa_out(params, out), (pool_k, pool_v)
 
-    # dense paged decode: gather the resident pages into the contiguous
-    # view, then the same grouped einsum as the contiguous dense path
-    ckg = gather_pages(pool_k, page_table)
-    cvg = gather_pages(pool_v, page_table)
-    qg = q.squeeze(2).reshape(b, hkv, g, hd)
-    scale = 1.0 / (hd ** 0.5)
-    logits = jnp.einsum("bkgd,bksd->bkgs", qg, ckg,
-                        preferred_element_type=jnp.float32) * scale
+    # dense paged decode: read the resident pages in their storage layout
+    # (the allocator only hands out valid page ids, so no bounds fill)
+    at = lead + (page_table,)
+    out = dense_decode(q.squeeze(2),
+                       pool_k.at[at].get(mode="promise_in_bounds"),
+                       pool_v.at[at].get(mode="promise_in_bounds"), mask)
+    out = jnp.asarray(out, q.dtype)[:, :, None, :]      # (B, H, 1, hd)
+    return common.gqa_out(params, out), (pool_k, pool_v)
+
+
+def dense_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                 mask: jnp.ndarray) -> jnp.ndarray:
+    """Dense attention of one query token per row against grouped K/V.
+
+    ``q`` is ``(B, H, hd)``; ``k``/``v`` are a contiguous ``(B, Hkv, S,
+    hd)`` cache, or resident pages ``(B, NB, Hkv, ps, hd)`` as the pool
+    stores them, whose key axis is (page, within-page) with ``S = NB·ps``;
+    ``mask`` is ``(B, S)``.  Query heads fold into (kv_head, group) and
+    contract against the grouped K/V directly — HBM traffic is the cache
+    once, not ×groups, and pages are never moved into a contiguous view —
+    accumulating in f32 via preferred_element_type instead of casting the
+    cache (an f32 cache copy would be hoisted to full stacked shape).
+    Returns f32 ``(B, H, hd)``.
+    """
+    b, h, hd = q.shape
+    paged = k.ndim == 5
+    hkv = k.shape[2] if paged else k.shape[1]
+    g = h // hkv
+    kv, keys = ("bnkpd", "np") if paged else ("bksd", "s")
+    key_shape = (k.shape[1], k.shape[3]) if paged else (k.shape[2],)
+    qg = q.reshape(b, hkv, g, hd)
+    logits = jnp.einsum(f"bkgd,{kv}->bkg{keys}", qg, k,
+                        preferred_element_type=jnp.float32)
+    logits = logits.reshape(b, hkv, g, -1) * (1.0 / (hd ** 0.5))
     logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
     p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bksd->bkgd", jnp.asarray(p, cvg.dtype),
-                     cvg, preferred_element_type=jnp.float32)
-    out = jnp.asarray(out, q.dtype).reshape(b, hkv * g, 1, hd)
-    return common.gqa_out(params, out), (pool_k, pool_v)
+    p = jnp.asarray(p, v.dtype).reshape((b, hkv, g) + key_shape)
+    out = jnp.einsum(f"bkg{keys},{kv}->bkgd", p, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, h, hd)

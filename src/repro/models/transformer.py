@@ -145,7 +145,7 @@ def layer_prefill(layer, x, cfg: ModelConfig, positions, sp: SharePrefill,
 def layer_decode(layer, x, cfg: ModelConfig, cache, pos, positions, *,
                  moe_ffn: bool, window: int = 0, plan=None, valid=None,
                  decode_impl: str = "auto", page_table=None,
-                 return_q: bool = False):
+                 pool_layer=None, return_q: bool = False):
     window = window or cfg.sliding_window      # native SWA (Mixtral)
     h = common.rmsnorm(layer["ln1"], x, cfg.rms_norm_eps)
     if _uses_mla(cfg):
@@ -161,7 +161,7 @@ def layer_decode(layer, x, cfg: ModelConfig, cache, pos, positions, *,
             layer["attn"], h, cfg, cache[0], cache[1], pos, positions,
             window=window, valid_mask=valid, plan=plan,
             decode_impl=decode_impl, page_table=page_table,
-            return_q=return_q)
+            pool_layer=pool_layer, return_q=return_q)
         a, cache = res[0], res[1]
     x = x + a
     h = common.rmsnorm(layer["ln2"], x, cfg.rms_norm_eps)
@@ -312,7 +312,11 @@ def decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
     ``cache["stack"]`` leaves are then the shared ``(L, P, Hkv, ps, hd)``
     page pools (prefix layers unsupported — the pool covers the scanned
     stack) and each attention layer appends/reads through the table; the
-    virtual cache length is ``page_table.shape[1] · page_size``.
+    virtual cache length is ``page_table.shape[1] · page_size``.  Dense
+    paged decode carries the whole pools through the layer loop, each
+    layer writing its pages in place at its layer index, so a donated
+    pool is updated without a copy; the sparse plan path scans one
+    layer's pool slice in and out.
 
     ``collect_queries`` additionally returns the step's per-layer
     post-rope query vectors ``(L_stack, B, H, hd)`` as a third output
@@ -392,16 +396,31 @@ def decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
 
             x, new_caches = jax.lax.scan(
                 body, x, (params["stack"], cache["stack"], plan_xs))
-    else:
-        if collect_queries:
-            raise ValueError("collect_queries requires a DecodePlan (the "
-                             "refresh path is sparse paged decode)")
+    elif collect_queries:
+        raise ValueError("collect_queries requires a DecodePlan (the "
+                         "refresh path is sparse paged decode)")
+    elif page_table is not None:
+        # the pools ride in the carry, indexed by layer: no per-layer slice
+        # goes in and no stacked pool comes out
+        def body(carry, xs):
+            x, pool_k, pool_v = carry
+            layer, idx = xs
+            x, (pool_k, pool_v) = layer_decode(
+                layer, x, cfg, (pool_k, pool_v), pos, positions,
+                moe_ffn=moe_ffn, window=window, valid=valid,
+                page_table=page_table, pool_layer=idx)
+            return (x, pool_k, pool_v), None
 
+        n_stack = cfg.num_layers - n_prefix
+        (x, pool_k, pool_v), _ = jax.lax.scan(
+            body, (x, *cache["stack"]),
+            (params["stack"], jnp.arange(n_stack)))
+        new_caches = (pool_k, pool_v)
+    else:
         def body(x, xs):
             layer, c = xs
             x, c = layer_decode(layer, x, cfg, c, pos, positions,
-                                moe_ffn=moe_ffn, window=window, valid=valid,
-                                page_table=page_table)
+                                moe_ffn=moe_ffn, window=window, valid=valid)
             return x, c
 
         x, new_caches = jax.lax.scan(body, x,

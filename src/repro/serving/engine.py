@@ -60,9 +60,10 @@ kernels.  Admission allocates ``(bucket + decode_extra) / page_size``
 pages (kept WAITING when the pool lacks headroom —
 ``pages_exhausted_steps`` counts the deferrals), prefill KV lands
 page-at-a-time (whole-cache or per layer under chunked admission), the
-decode append is a single in-place sliver scatter through the table
-(retiring ``grow_cache`` reallocation and whole-row ``cache_insert``
-copies on this path), and EOS/finish frees the slot's pages for reuse.
+decode step takes the pool donated and rewrites each slot's current page
+in place (retiring ``grow_cache`` reallocation and whole-row
+``cache_insert`` copies on this path), and EOS/finish frees the slot's
+pages for reuse.
 Because batch shape is now just page-table rows, the scheduler's
 single-bucket restriction is lifted: ONE scheduler serves all requests,
 and slots of different former buckets coexist in one decode batch (each
@@ -558,6 +559,13 @@ class ServingEngine:
         so ONE compiled program serves every bucket mix — the paged
         scheduler never recompiles on cross-bucket churn.
 
+        The pool is donated: the caller's pool arrays are consumed and the
+        returned pool takes their place.  The dense step carries the pool
+        through its layer loop and writes each slot's current page back
+        whole, so XLA updates it in place and the pool is never held
+        twice; the sparse twins scan one layer's slice, donated all the
+        same.
+
         ``collect_queries`` compiles the refresh-mode twin (sparse only):
         the same step additionally returns the per-layer post-rope decode
         queries ``(L, B, H, hd)`` the scheduler rings up into each slot's
@@ -594,7 +602,8 @@ class ServingEngine:
                     return self.model.decode(
                         params, token, cache, pos, prompt_lens=plens,
                         prefill_len=pflens, page_table=page_table)
-            self._decode_cache[key] = jax.jit(decode_step)
+            self._decode_cache[key] = jax.jit(decode_step,
+                                              donate_argnames="cache")
         return self._decode_cache[key]
 
     def _chunk_tokens(self, seq: int) -> int:

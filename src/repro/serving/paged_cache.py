@@ -23,9 +23,10 @@ Conventions:
   different buckets coexist in one decode batch because shape-wise the
   batch is just ``(nslots, table_blocks)`` table rows.
 * Prefill KV is written page-at-a-time (whole-cache or layer-at-a-time
-  for chunked prefill); the decode append writes a single
-  ``(Hkv, head_dim)`` sliver in place via the page table, retiring the
-  ``grow_cache`` reallocation and whole-row ``cache_insert`` copies.
+  for chunked prefill); the decode append rewrites each slot's current
+  page (its ``(Hkv, head_dim)`` token sliver set inside it) in the
+  donated pool, retiring the ``grow_cache`` reallocation and whole-row
+  ``cache_insert`` copies.
 * **Pages are refcounted.**  :meth:`PageAllocator.acquire` grants fresh
   pages at refcount 1; :meth:`PageAllocator.share` takes an extra
   reference on already-allocated pages (the prefix-sharing path: a
@@ -207,9 +208,12 @@ def init_paged_pool(cfg, *, num_pages: int, page_size: int,
                     dtype=jnp.float32):
     """Zeroed page-pool cache pytree ``{"prefix": [], "stack": (K, V)}``.
 
-    Layer axis leads so the decode scan slices one layer's
-    ``(num_pages, Hkv, page_size, head_dim)`` pool per step, mirroring the
-    contiguous stack layout.
+    Layer axis leads, mirroring the contiguous stack layout.  The dense
+    paged decode step takes the pool donated, carries it whole through
+    its layer loop and writes each slot's current page at ``[layer,
+    page]``, so the pool is updated in place and never held twice; the
+    sparse twins scan one layer's ``(num_pages, Hkv, page_size,
+    head_dim)`` slice.
     """
     from repro.models.transformer import num_prefix_layers
     if cfg.mla.enabled:

@@ -66,9 +66,9 @@ request).  Core slot mechanics:
     validates the pool holds at least one max-length request, so decode
     progress guarantees eventual admission).  Prefill KV is scattered
     page-at-a-time (whole-cache on the one-shot path, per layer under
-    chunked admission) and the decode append is an in-place sliver scatter
-    through the table — no ``grow_cache`` reallocation, no whole-row
-    ``cache_insert`` copies.  Because batch geometry is now just
+    chunked admission) and the decode step rewrites each slot's current
+    page in the donated pool — no ``grow_cache`` reallocation, no
+    whole-row ``cache_insert`` copies.  Because batch geometry is now just
     page-table rows, ONE paged scheduler serves ALL buckets: each request
     prefills at its own bucket, keeps a per-slot ``prefill_len``
     (``pflens``), and its DecodePlan row — built at its own allocation
@@ -1151,7 +1151,7 @@ class SlotScheduler:
         if self.paged:
             # _admit gated on headroom, so the grant always succeeds; the
             # prefill KV fills the first seq // page_size pages, the rest
-            # are the decode tail the sliver append grows into
+            # are the decode tail the decode append grows into
             pages = self._alloc_slot_pages(slot, self._pages_needed(r))
             self.cache = paged_cache.insert_prefill(
                 self.cache, result.cache, pages[: seq // self.page_size])

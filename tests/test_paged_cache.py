@@ -34,6 +34,7 @@ from repro.kernels.block_sparse_attn import (
 from repro.kernels.decode_attn import flash_decode_plan_paged, gather_pages
 from repro.kernels.indices import compact_block_mask
 from repro.models import build_model
+from repro.models.attention import dense_decode
 from repro.serving import (
     EngineConfig,
     NULL_PAGE,
@@ -41,8 +42,9 @@ from repro.serving import (
     Request,
     ServingEngine,
 )
-from repro.serving import cache_ops, paged_cache
-from test_decode_conformance import CASES, SHARDABLE, CaseData, build_case, _run
+from repro.serving import cache_ops, decode_plan, paged_cache
+from test_decode_conformance import (CASES, SHARDABLE, CaseData, build_case,
+                                     _run, _tol)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TESTS = os.path.dirname(os.path.abspath(__file__))
@@ -194,6 +196,22 @@ def test_paged_decode_bitmatches_contiguous(case, impl):
     np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_c))
 
 
+@pytest.mark.parametrize("case", CASES, ids=lambda c: c.name)
+def test_dense_paged_read_matches_contiguous(case):
+    """The dense paged step's attention reads the resident pages in pool
+    layout ``(B, NB, Hkv, ps, hd)`` and contracts over (page, within-page);
+    against the contiguous dense decode's single key axis it is the same
+    sum, whose order the backend may pick per shape (the CPU's differs by
+    an ulp for MHA), so the dtype's conformance tolerance holds it."""
+    data = build_case(case)
+    pk, pv, table = _page_in(data.cache_k, data.cache_v, case.bs)
+    pages = lambda pool: pool.at[table].get(mode="promise_in_bounds")
+    out_p = dense_decode(data.q, pages(pk), pages(pv), data.valid)
+    out_c = dense_decode(data.q, data.cache_k, data.cache_v, data.valid)
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_c),
+                               atol=_tol(case), rtol=_tol(case))
+
+
 def test_gather_pages_roundtrip():
     data = build_case(CASES[0])
     pk, _, table = _page_in(data.cache_k, data.cache_v, CASES[0].bs)
@@ -302,27 +320,104 @@ def _mixed_requests():
             + _requests((3, 5), seq=SEQ, base=20))
 
 
-@pytest.mark.parametrize("sparse", [False, True],
-                         ids=["dense_decode", "sparse_decode"])
-def test_paged_scheduler_bitmatches_contiguous(setup, sparse):
+# (sparse decode, max_new per request).  The page-boundary mix runs slot 0
+# past its first decode page (64 tokens) while slot 1 serves a short
+# request and refills, then slot 0 finishes and sits inert (its appends in
+# the null page) while slot 1 crosses its own page boundary.
+SERVES = {"dense_decode": (False, (5, 2, 4, 3)),
+          "sparse_decode": (True, (5, 2, 4, 3)),
+          "dense_decode_page_boundary": (False, (66, 3, 66)),
+          "sparse_decode_page_boundary": (True, (66, 3, 66))}
+
+
+@pytest.mark.parametrize("serve", SERVES)
+def test_paged_scheduler_bitmatches_contiguous(setup, serve):
     """Single bucket: the paged scheduler's greedy tokens bit-match the
     contiguous scheduler's (which itself bit-matches the legacy path)."""
     get_engine = setup
+    sparse, max_new = SERVES[serve]
     eng_c = get_engine(seq_buckets=(SEQ,), decode_sparse=sparse,
                        scheduler=True)
-    reqs_c = _requests((5, 2, 4, 3))
+    reqs_c = _requests(max_new)
     eng_c.serve(reqs_c, seed=0)
 
     eng_p = get_engine(seq_buckets=(SEQ,), decode_sparse=sparse, paged=True)
-    reqs_p = _requests((5, 2, 4, 3))
+    reqs_p = _requests(max_new)
     eng_p.serve(reqs_p, seed=0)
 
     for a, b in zip(reqs_c, reqs_p):
+        assert len(b.output_tokens) == b.max_new_tokens
         np.testing.assert_array_equal(a.output_tokens, b.output_tokens)
     stats = eng_p.page_pool_stats
     assert stats["page_size"] == max(eng_p.sp.cfg.block_size, 1)
     assert 0 < stats["peak_pages"] < stats["num_pages"]
     assert eng_p.pages_exhausted_steps == 0    # auto-sized pool never defers
+
+
+@pytest.mark.parametrize("sparse", [False, True],
+                         ids=["dense_decode", "sparse_decode"])
+def test_paged_decode_donates_pool(setup, sparse):
+    """One step of the engine's paged program consumes the pool it was
+    given (the input pool arrays are deleted) and returns the pool that
+    replaces it, so the step never holds two pools."""
+    eng = setup(seq_buckets=(SEQ,), decode_sparse=sparse, paged=True)
+    ps = eng.sp.cfg.block_size
+    b, nb = eng.ecfg.max_batch, (SEQ + eng.ecfg.decode_extra) // ps
+    cache = paged_cache.init_paged_pool(CFG, num_pages=1 + b * nb,
+                                        page_size=ps)
+    table = jnp.arange(1, 1 + b * nb, dtype=jnp.int32).reshape(b, nb)
+    vec = jnp.full((b,), SEQ, jnp.int32)
+    args = (eng.params, jnp.zeros((b, 1), jnp.int32), cache, table, vec,
+            vec, vec)
+    if sparse:
+        args += (decode_plan.empty_decode_plan(
+            CFG, batch=b, cache_len=nb * ps, block_size=ps),)
+    logits, new = eng._decode_fn_paged(b, nb, sparse)(*args)
+    assert logits.shape == (b, CFG.vocab_size)
+    assert all(a.is_deleted() for a in cache["stack"])
+    assert not any(a.is_deleted() for a in new["stack"])
+    assert [a.shape for a in new["stack"]] == [a.shape for a in
+                                               cache["stack"]]
+
+
+def test_paged_decode_step_across_page_boundary(setup):
+    """Drive the engine's dense paged program and its contiguous twin step
+    by step: slot 0 decodes across its page boundary while slot 1 sits
+    inert on the null page.  Slot 0's pages hold what the contiguous cache
+    holds (layer 0 bitwise: its K/V depend on the token alone; deeper
+    layers and the logits within f32 tolerance, since the paged read sums
+    over (page, within-page) in an order the backend picks), and no page
+    outside slot 0's run and the null page is ever written."""
+    eng = setup(seq_buckets=(SEQ,), paged=True)
+    ps = eng.sp.cfg.block_size
+    b, nb = 2, (SEQ + eng.ecfg.decode_extra) // ps
+    cl = nb * ps
+    params = eng.params
+    pool = paged_cache.init_paged_pool(CFG, num_pages=1 + 2 * nb,
+                                       page_size=ps)
+    cont = eng.model.init_cache(b, cl, dtype=pool["stack"][0].dtype)
+    table = np.zeros((b, nb), np.int32)
+    table[0] = 1 + nb + np.arange(nb)[::-1]     # pages nb+1..2nb, reversed
+    pos = np.array([ps - 3, 2 * ps + 5], np.int32)
+    full = jnp.full((b,), cl, jnp.int32)        # every slot <= pos valid
+    paged_step = eng._decode_fn_paged(b, nb)
+    cont_step = eng._decode_fn(b, cl, cl)
+    toks = np.random.default_rng(0).integers(0, CFG.vocab_size, (6, b, 1))
+    for tok in jnp.asarray(toks, jnp.int32):
+        lp, pool = paged_step(params, tok, pool, jnp.asarray(table),
+                              jnp.asarray(pos), full, full)
+        lc, cont = cont_step(params, tok, cont, jnp.asarray(pos), full)
+        np.testing.assert_allclose(np.asarray(lp[0]), np.asarray(lc[0]),
+                                   rtol=2e-5, atol=2e-5)
+        pos[0] += 1
+    assert pos[0] > ps + 1                      # crossed into a new page
+    for pk, ck in zip(pool["stack"], cont["stack"]):
+        pk, ck = np.asarray(pk), np.asarray(ck)
+        run = pk[:, table[0]]                   # (L, NB, Hkv, ps, hd)
+        run = run.transpose(0, 2, 1, 3, 4).reshape(ck[:, 0].shape)
+        np.testing.assert_array_equal(run[0], ck[0, 0])
+        np.testing.assert_allclose(run, ck[:, 0], rtol=2e-5, atol=2e-5)
+        assert not pk[:, 1:1 + nb].any()        # nobody's pages: untouched
 
 
 def test_paged_mixed_buckets_one_batch(setup):

@@ -122,3 +122,50 @@ def test_paged_decode(one_chip, bs):
               ((SLOTS, HKV), jnp.int32),
               ((SLOTS, HKV, nb, H // HKV), jnp.bool_),
               ((SLOTS, N), jnp.bool_)], one_chip)
+
+
+def test_paged_decode_step_updates_pool_in_place(one_chip):
+    """The engine's dense paged decode program at internlm2-1.8b widths
+    (24 layers, 8 slots, 68-page tables, a 360-page bf16 pool) takes the
+    pool it is given and writes it in place: the output aliases the whole
+    pool, no temporary reaches one layer's K+V pool slice, and the only
+    pool-shaped values of the entry computation are its parameters, the
+    layer loop, and the loop's results."""
+    import re
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving import EngineConfig, ServingEngine
+
+    layers, pages, ps, nb = 24, 360, 128, (N + 512) // 128
+    model = build_model(get_config("internlm2-1.8b"))
+    eng = ServingEngine(model, None, model.default_share_prefill(),
+                        EngineConfig(method="dense", paged=True,
+                                     max_batch=SLOTS))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = jax.ShapeDtypeStruct((layers, pages, HKV, ps, D), jnp.bfloat16,
+                                sharding=one_chip)
+    vec = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    compiled = eng._decode_fn_paged(SLOTS, nb).lower(
+        params, vec(SLOTS, 1), {"prefix": [], "stack": (pool, pool)},
+        vec(SLOTS, nb), vec(SLOTS), vec(SLOTS), vec(SLOTS)).compile()
+
+    pool_bytes = 2 * layers * pages * HKV * ps * D * 2
+    layer_bytes = pool_bytes // layers
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < layer_bytes
+
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    shape = f"bf16[{layers},{pages},{HKV},{ps},{D}]"
+    ops = set()
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][\w-]*)\(", line)
+        if m and shape in m.group(1):
+            ops.add(m.group(2))
+    assert "while" in ops
+    assert ops <= {"parameter", "while", "get-tuple-element", "tuple"}, ops
