@@ -99,7 +99,7 @@ def main(argv=None) -> int:
         gc.collect()
         ref = s.reference_params(seed)
         t0 = time.perf_counter()
-        gaps = check.reference_gaps(ref, s.sizes, picked,
+        gaps = check.reference_gaps(s.family, ref, s.sizes, picked,
                                     control=i < args.control,
                                     pad=s.limits.get("pad", 1024))
         check_s = time.perf_counter() - t0

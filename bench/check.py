@@ -2,11 +2,12 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the one with
-the longest prompt, is run through the plain reference (``reference.py``)
-over each prompt followed by its served tokens.  At every served position
-the reference's float32 logits give the gap by which the served token lies
-below the reference's best token.  The run's ``logit_gap`` is the widest
-such gap; it is held to the cell's limit (``bench/limits/<cell>.json``).
+the longest prompt, is run through the plain reference (the family's
+``logits``) over each prompt followed by its served tokens.  At every
+served position the reference's float32 logits give the gap by which the
+served token lies below the reference's best token.  The run's
+``logit_gap`` is the widest such gap; it is held to the cell's limit
+(``bench/limits/<cell>.json``).
 ``failed_requests`` counts requests that did not finish ``done``; its
 limit is 0.
 
@@ -20,7 +21,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-from bench import reference, traffic
+from bench import traffic
 
 
 def sample(requests: Sequence, seed: int, min_tokens: int,
@@ -70,22 +71,23 @@ def widest_gap(ref_logits: np.ndarray, tokens: np.ndarray) -> float:
     return float(np.max(best - got))
 
 
-def reference_gaps(params, sizes: Dict, picked: Sequence, *,
+def reference_gaps(family, params, sizes: Dict, picked: Sequence, *,
                    control: bool = False, pad: int = 1024) -> Dict:
     """Widest gap of the served tokens (and, with ``control``, of the
-    float8 reference's first choices) over the picked requests."""
+    float8 reference's first choices) over the picked requests, by the
+    ``family``'s reference."""
     gap, ctl, first = 0.0, 0.0, 0.0
     tokens = 0
     for r in picked:
         out = np.asarray(r.output_tokens, np.int64)
         seq = np.concatenate([np.asarray(r.prompt, np.int64), out[:-1]])
         rows = served_rows(len(r.prompt), len(out))
-        ref = reference.logits(params, sizes, seq, rows, pad=pad)
+        ref = family.logits(params, sizes, seq, rows, pad=pad)
         gap = max(gap, widest_gap(ref, out))
         first = max(first, widest_gap(ref[:1], out[:1]))
         if control:
-            low = reference.logits(params, sizes, seq, rows,
-                                   precision="fp8", pad=pad)
+            low = family.logits(params, sizes, seq, rows, precision="fp8",
+                                pad=pad)
             ctl = max(ctl, widest_gap(ref, low.argmax(-1)))
         tokens += len(out)
     res = {"logit_gap": gap, "first_token_gap": first,

@@ -4,9 +4,11 @@ Everything specific to a configuration, mix, cell or metric is read from
 files named after it (see ``bench/__init__.py``); this module holds only
 what every cell does:
 
-1. build the program's model from the configuration file's sizes, fill its
-   parameter tree from the seed (``weights.py``) in the served dtype, and
-   build the ``ServingEngine`` the file's ``engine`` fields describe;
+1. load the configuration's family (``bench/families/<family>.py``),
+   build the program's model from the file's sizes as the family maps
+   them, fill its parameter tree from the seed (``weights.py``) in the
+   served dtype, and build the ``ServingEngine`` the file's ``engine``
+   fields describe;
 2. warm up with one serve of the mix's warm-up requests (one per sequence
    bucket the run reaches, from a stream no run uses), so every program the
    window drives is compiled or loaded from the persistent cache;
@@ -29,6 +31,7 @@ import tempfile
 import time
 from collections import defaultdict
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -38,14 +41,8 @@ ROOT = Path(__file__).resolve().parents[1]
 # path is part of the cache key), so only a checkout's first run compiles
 CACHE_DIR = ROOT / ".jax_cache"
 
-# configuration-file keys (the published names) -> repro ModelConfig fields
-MODEL_FIELDS = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "rms_norm_eps": "rms_norm_eps", "tie_word_embeddings": "tie_embeddings",
-}
+# the family of a configuration file that names none
+DEFAULT_FAMILY = "dense_gqa"
 
 
 def use_compile_cache() -> None:
@@ -75,8 +72,8 @@ def load_json(path: Path) -> Dict:
 
 class Bench:
     """The benchmark's files: ``spec`` (BENCHMARK.json) and the directory
-    holding ``configs/``, ``traffic/``, ``limits/``, ``metrics/`` and
-    ``peaks.json``."""
+    holding ``configs/``, ``families/``, ``traffic/``, ``limits/``,
+    ``metrics/`` and ``peaks.json``."""
 
     def __init__(self, spec: Path = ROOT / "BENCHMARK.json",
                  home: Path = ROOT / "bench"):
@@ -117,19 +114,43 @@ class Bench:
                 if (cell in m["workloads"] if "workloads" in m
                     else m["moves"] in names)]
 
-    def reader(self, metric: str):
-        path = self.home / "metrics" / f"{metric}.py"
+    def _load(self, kind: str, name: str):
+        path = self.home / kind / f"{name}.py"
         spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_')}", path)
+            f"bench_{kind}_{name.replace('.', '_')}", path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        return mod.read
+        return mod
+
+    def reader(self, metric: str):
+        return self._load("metrics", metric).read
+
+    def family(self, name: str):
+        """The module ``families/<name>.py``, which holds every fact of one
+        architecture that the benchmark uses:
+
+        * ``model_fields(conf)``: the ``ModelConfig`` fields a configuration
+          file sets, laid over its registry entry;
+        * ``layout(sizes)``: ``path -> shape`` of the parameter tree that
+          ``weights.fill`` requires of the program and fills;
+        * ``fan_in(path, shape)``: a leaf's contracted size, its standard
+          deviation ``fan_in ** -0.5``;
+        * ``logits(params, sizes, tokens, rows, *, precision, pad, block)``:
+          the plain float32 reference, and its float8 control;
+        * ``matmul_params(sizes)``: weights multiplied once per token in
+          the ``layers`` and in the ``head``;
+          ``attention_flops(blocks, block, sizes)``: prefill attention
+          over ``blocks`` (block x block) tiles kept in each head of each
+          layer; ``key_flops(sizes)``: decode attention per key of
+          context; both summed over heads and layers."""
+        return self._load("families", name)
 
 
 @dataclasses.dataclass
 class Run:
     """What one run leaves for the metric readers."""
     cell: Dict
+    family: ModuleType        # the configuration's family module
     sizes: Dict               # the configuration file's model sizes
     block: int                # the pattern block size (tokens)
     requests: List            # repro Request objects, all offered
@@ -166,14 +187,14 @@ class CompileCounter:
         return sum(self.counts[e] for e in self.EVENTS)
 
 
-def model_config(conf: Dict):
+def model_config(conf: Dict, family):
     """The program's ModelConfig for a configuration file: the registry
-    entry with every size the file states laid over it."""
+    entry with every field the ``family`` maps from the file laid over
+    it."""
     from repro.configs import get_config
     from repro.configs.base import SharePrefillConfig
     cfg = get_config(conf["registry"])
-    over = {MODEL_FIELDS[k]: v for k, v in conf["model"].items()
-            if k in MODEL_FIELDS}
+    over = family.model_fields(conf)
     over["dtype"] = conf["dtype"]
     if "share_prefill" in conf:
         over["share_prefill"] = SharePrefillConfig(**conf["share_prefill"])
@@ -206,11 +227,12 @@ class Session:
         self.dev = device or jax.devices()[0]
         self.peaks = (bench.peaks(self.dev.device_kind)
                       if self.dev.platform == "tpu" else {})
+        self.family = bench.family(self.conf.get("family", DEFAULT_FAMILY))
         self.sizes = self.conf["model"]
         self.dtype = jnp.dtype(self.conf["dtype"])
         self.clock = CompileCounter()
 
-        self.model = build_model(model_config(self.conf))
+        self.model = build_model(model_config(self.conf, self.family))
         self.shape = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
         self.sp = self.model.default_share_prefill()
         fields = {k: tuple(v) if isinstance(v, list) else v
@@ -239,8 +261,8 @@ class Session:
         from bench import weights
         self.engine.params = None
         gc.collect()
-        self.engine.params = weights.fill(self.shape, self.sizes, seed,
-                                          self.dtype)
+        self.engine.params = weights.fill(self.shape, self.family,
+                                          self.sizes, seed, self.dtype)
 
     def serve(self, seed: int, seconds: float, trace: bool = False,
               rate: float = 0.0) -> Run:
@@ -271,8 +293,8 @@ class Session:
                 shutil.rmtree(tdir, ignore_errors=True)
             self.log("trace", read_s=round(time.perf_counter() - t1, 3),
                      busy_s=round(summary.busy_s, 3))
-        return Run(self.cell, self.sizes, self.sp.cfg.block_size, reqs,
-                   setup_s, window_s, compiles,
+        return Run(self.cell, self.family, self.sizes,
+                   self.sp.cfg.block_size, reqs, setup_s, window_s, compiles,
                    self.engine.pages_exhausted_steps, self.peaks, summary)
 
     def metrics(self, run: Run, trace: bool) -> Dict:
@@ -285,7 +307,7 @@ class Session:
 
     def reference_params(self, seed: int):
         from bench import weights
-        return weights.make(weights.layout(self.sizes), seed, self.dtype)
+        return weights.make(self.family, self.sizes, seed, self.dtype)
 
 
 def run_cell(bench: Bench, name: str, seed: int, seconds: float,
@@ -307,8 +329,8 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float,
     s.engine = None
     gc.collect()
     t2 = time.perf_counter()
-    gaps = check.reference_gaps(s.reference_params(seed), s.sizes, picked,
-                                pad=s.limits.get("pad", 1024))
+    gaps = check.reference_gaps(s.family, s.reference_params(seed), s.sizes,
+                                picked, pad=s.limits.get("pad", 1024))
     failed = sum(r.state != "done" for r in run.requests)
     numbers = check.numbers(gaps["logit_gap"], failed, s.limits)
     correct = check.correct(numbers, picked)

@@ -8,5 +8,6 @@ from bench import work
 def read(run):
     if run.trace is None or not run.done:
         return None
-    return 100.0 * work.served_flops(run.done, run.block, run.sizes) \
+    flops = work.served_flops(run.done, run.block, run.family, run.sizes)
+    return 100.0 * flops \
         / (run.trace.window_s * run.peaks["bf16_flops_per_s"])

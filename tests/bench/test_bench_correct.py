@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 from _bench_path import ROOT
-from bench import reference, weights
 from bench.harness import Bench, run_cell
 
 DATA = ROOT / "tests" / "bench" / "data" / "home"
@@ -22,6 +21,7 @@ def tiny(tmp_path):
     home = tmp_path / "bench"
     shutil.copytree(DATA, home)
     shutil.copytree(ROOT / "bench" / "metrics", home / "metrics")
+    shutil.copytree(ROOT / "bench" / "families", home / "families")
     shutil.copy(ROOT / "bench" / "peaks.json", home / "peaks.json")
     return Bench(home / "spec.json", home)
 
@@ -71,13 +71,13 @@ def test_float8_control_fails_the_limit(tiny, monkeypatch):
 
     def float8_served(self, seed, seconds, trace=False, rate=0.0):
         run = serve(self, seed, seconds, trace, rate)
-        params = weights.make(weights.layout(self.sizes), seed, self.dtype)
+        params = self.reference_params(seed)
         for r in run.done:
             seq = list(r.prompt)
             for _ in range(len(r.output_tokens)):
-                low = reference.logits(params, self.sizes, np.asarray(seq),
-                                       np.asarray([len(seq) - 1]), pad=512,
-                                       precision="fp8")
+                low = self.family.logits(params, self.sizes, np.asarray(seq),
+                                         np.asarray([len(seq) - 1]), pad=512,
+                                         precision="fp8")
                 seq.append(int(low[0].argmax()))
             r.output_tokens = seq[len(r.prompt):]
         return run

@@ -136,6 +136,7 @@ def test_serve_a_cell_reports_its_spans(tmp_path):
     home = tmp_path / "bench"
     shutil.copytree(DATA / "home", home)
     shutil.copytree(ROOT / "bench" / "metrics", home / "metrics")
+    shutil.copytree(ROOT / "bench" / "families", home / "families")
     shutil.copy(ROOT / "bench" / "peaks.json", home / "peaks.json")
     bench = Bench(home / "spec.json", home)
     out = spans.serve(bench, "tiny.tinymix", 4, 2.0, trace=True)

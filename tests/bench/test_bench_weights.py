@@ -7,7 +7,9 @@ import pytest
 
 from _bench_path import ROOT
 from bench import weights
-from bench.harness import model_config
+from bench.harness import Bench, model_config
+
+DENSE = Bench().family("dense_gqa")
 
 TINY = json.loads((ROOT / "tests/bench/data/home/configs/tiny.json"
                    ).read_text())
@@ -15,7 +17,7 @@ TINY = json.loads((ROOT / "tests/bench/data/home/configs/tiny.json"
 
 def _program_shape(conf):
     from repro.models import build_model
-    model = build_model(model_config(conf))
+    model = build_model(model_config(conf, DENSE))
     return jax.eval_shape(model.init, jax.random.PRNGKey(0))
 
 
@@ -34,14 +36,14 @@ def test_layout_is_the_programs_tree_at_full_size(name):
         (ROOT / f"bench/configs/{name}.json").read_text()))
     got = {weights._path(kp): tuple(x.shape) for kp, x in
            jax.tree_util.tree_flatten_with_path(_program_shape(conf))[0]}
-    assert got == weights.layout(conf["model"])
+    assert got == DENSE.layout(conf["model"])
 
 
 def test_fill_is_the_seeds_and_matches_make():
     shape = _program_shape(TINY)
-    a = weights.fill(shape, TINY["model"], 3)
-    b = weights.make(weights.layout(TINY["model"]), 3)
-    c = weights.fill(shape, TINY["model"], 2**31 + 3)
+    a = weights.fill(shape, DENSE, TINY["model"], 3)
+    b = weights.make(DENSE, TINY["model"], 3)
+    c = weights.fill(shape, DENSE, TINY["model"], 2**31 + 3)
     wq = np.asarray(a["stack"]["attn"]["wq"], np.float32)
     assert np.array_equal(wq, np.asarray(b["stack/attn/wq"], np.float32))
     assert not np.array_equal(
@@ -56,4 +58,4 @@ def test_a_tree_the_layout_does_not_know_is_refused():
     shape = _program_shape(TINY)
     shape["extra"] = jax.ShapeDtypeStruct((3,), jax.numpy.float32)
     with pytest.raises(ValueError):
-        weights.fill(shape, TINY["model"], 1)
+        weights.fill(shape, DENSE, TINY["model"], 1)
