@@ -23,10 +23,21 @@ class MoEConfig:
     capacity_factor: float = 1.25   # dispatch capacity multiplier
     router_aux_loss_weight: float = 0.01
     router_z_loss_weight: float = 1e-3
+    norm_topk: bool = True          # renormalize the top-k router weights
+    # expert parallelism: this device holds routed experts
+    # [first_held, first_held + num_held); the router still scores all
+    # ``num_experts``.  0 = every expert is held here.
+    num_held: int = 0
+    first_held: int = 0
 
     @property
     def enabled(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.num_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,10 +49,22 @@ class MLAConfig:
     qk_nope_head_dim: int = 128     # non-rotary head dim
     qk_rope_head_dim: int = 64      # rotary (shared-key) head dim
     v_head_dim: int = 128
+    # YaRN rope scaling (DeepSeek-V2); rope_factor 1 = plain rope
+    rope_factor: float = 1.0
+    rope_original_max_positions: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
     @property
     def enabled(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one cached latent row: c_kv then the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)

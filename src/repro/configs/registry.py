@@ -17,6 +17,7 @@ from repro.configs.recurrentgemma_9b import CONFIG as _recurrentgemma
 from repro.configs.phi3_mini_3_8b import CONFIG as _phi3
 from repro.configs.llama3_8b_262k import CONFIG as _llama3_262k
 from repro.configs.qwen2_5_7b import CONFIG as _qwen2_5
+from repro.configs.deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 
 # The ten assigned architectures (spec order).
 ASSIGNED: Dict[str, ModelConfig] = {
@@ -38,7 +39,8 @@ PAPER_MODELS: Dict[str, ModelConfig] = {
     "qwen2.5-7b": _qwen2_5,
 }
 
-REGISTRY: Dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS}
+REGISTRY: Dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS,
+                                    "deepseek-v2-lite": _deepseek_v2_lite}
 
 # (arch, shape) pairs that are skipped, with the DESIGN.md §6 justification.
 SKIP_PAIRS = {

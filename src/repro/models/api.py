@@ -80,12 +80,13 @@ def build_model(cfg: ModelConfig, dtype=jnp.float32) -> Model:
                 positions=positions, embeds=embeds)
         dec = lambda p, token, cache, pos, positions=None, window=0, \
             embeds=None, plan=None, prompt_lens=None, prefill_len=0, \
-            decode_impl="auto", page_table=None, collect_queries=False: \
-            transformer.decode_step(
+            decode_impl="auto", page_table=None, collect_queries=False, \
+            expert_rows=False: transformer.decode_step(
                 p, cfg, token, cache, pos, positions, window=window,
                 embeds=embeds, plan=plan, prompt_lens=prompt_lens,
                 prefill_len=prefill_len, decode_impl=decode_impl,
-                page_table=page_table, collect_queries=collect_queries)
+                page_table=page_table, collect_queries=collect_queries,
+                expert_rows=expert_rows)
         ic = lambda batch, cache_len, dtype=jnp.float32: \
             transformer.init_cache(cfg, batch, cache_len, dtype)
         pc = chunked_prefill.make_chunk_prefill(cfg)

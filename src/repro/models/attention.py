@@ -410,19 +410,9 @@ def _attention_decode_paged(params, cfg, q, k, v, pool_k, pool_v, pos,
     sv = page_table.shape[1] * ps
     if not jnp.ndim(pos):
         raise ValueError("paged decode requires per-slot (vector) pos")
-    rows = jnp.arange(b)
     lead = () if layer is None else (layer,)
-    cur = lead + (page_table[rows, pos // ps],)     # each slot's page
-    within = pos % ps
-
-    def append(pool, new):
-        page = pool[cur]                            # (B, Hkv, ps, hd)
-        page = page.at[rows, :, within].set(
-            new[:, :, 0, :].astype(pool.dtype))
-        return pool.at[cur].set(page)
-
-    pool_k = append(pool_k, k)
-    pool_v = append(pool_v, v)
+    pool_k = paged_append(pool_k, k, page_table, pos, lead)
+    pool_v = paged_append(pool_v, v, page_table, pos, lead)
     # pool layout ([L,] P, Hkv, ps, hd): heads axis shards exactly like the
     # contiguous cache's; pages replicate across the batch by construction
     axes = (None,) * len(lead) + (None, "kv_heads", None, "heads")
@@ -454,17 +444,37 @@ def _attention_decode_paged(params, cfg, q, k, v, pool_k, pool_v, pos,
         return common.gqa_out(params, out), (pool_k, pool_v)
 
     # dense paged decode: read the resident pages in their storage layout
-    # (the allocator only hands out valid page ids, so no bounds fill)
-    at = lead + (page_table,)
-    out = dense_decode(q.squeeze(2),
-                       pool_k.at[at].get(mode="promise_in_bounds"),
-                       pool_v.at[at].get(mode="promise_in_bounds"), mask)
+    out = dense_decode(q.squeeze(2), paged_pages(pool_k, page_table, lead),
+                       paged_pages(pool_v, page_table, lead), mask)
     out = jnp.asarray(out, q.dtype)[:, :, None, :]      # (B, H, 1, hd)
     return common.gqa_out(params, out), (pool_k, pool_v)
 
 
+def paged_append(pool, new, page_table, pos, lead=()):
+    """Write one token per slot into its current page: ``new`` is ``(B,
+    Hkv, 1, hd)``, ``pool`` ``(*lead_dims, P, Hkv, ps, hd)`` addressed at
+    ``lead``.  Each slot's page (its logical block ``pos // ps`` through
+    the table) is read, the token set at ``pos % ps`` inside it, and the
+    whole page written back, which keeps the pool's storage layout."""
+    rows = jnp.arange(new.shape[0])
+    cur = tuple(lead) + (page_table[rows, pos // pool.shape[-2]],)
+    page = pool[cur]                                # (B, Hkv, ps, hd)
+    page = page.at[rows, :, pos % pool.shape[-2]].set(
+        new[:, :, 0, :].astype(pool.dtype))
+    return pool.at[cur].set(page)
+
+
+def paged_pages(pool, page_table, lead=()):
+    """The slots' resident pages ``(B, NB, Hkv, ps, hd)`` in their storage
+    layout (the allocator only hands out valid page ids, so no bounds
+    fill)."""
+    return pool.at[tuple(lead) + (page_table,)].get(
+        mode="promise_in_bounds")
+
+
 def dense_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                 mask: jnp.ndarray) -> jnp.ndarray:
+                 mask: jnp.ndarray,
+                 scale: Optional[float] = None) -> jnp.ndarray:
     """Dense attention of one query token per row against grouped K/V.
 
     ``q`` is ``(B, H, hd)``; ``k``/``v`` are a contiguous ``(B, Hkv, S,
@@ -475,7 +485,9 @@ def dense_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     once, not ×groups, and pages are never moved into a contiguous view —
     accumulating in f32 via preferred_element_type instead of casting the
     cache (an f32 cache copy would be hoisted to full stacked shape).
-    Returns f32 ``(B, H, hd)``.
+    ``scale`` defaults to ``hd ** -0.5``; ``v`` may be wider than the
+    output needs (the latent MQA passes its key rows as values).
+    Returns f32 ``(B, H, hd_v)``.
     """
     b, h, hd = q.shape
     paged = k.ndim == 5
@@ -486,10 +498,11 @@ def dense_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     qg = q.reshape(b, hkv, g, hd)
     logits = jnp.einsum(f"bkgd,{kv}->bkg{keys}", qg, k,
                         preferred_element_type=jnp.float32)
-    logits = logits.reshape(b, hkv, g, -1) * (1.0 / (hd ** 0.5))
+    scale = (1.0 / (hd ** 0.5)) if scale is None else scale
+    logits = logits.reshape(b, hkv, g, -1) * scale
     logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
     p = jax.nn.softmax(logits, axis=-1)
     p = jnp.asarray(p, v.dtype).reshape((b, hkv, g) + key_shape)
     out = jnp.einsum(f"bkg{keys},{kv}->bkgd", p, v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, h, hd)
+    return out.reshape(b, h, v.shape[-1])

@@ -57,10 +57,10 @@ from repro.kernels import batched_sparse_attention_fn
 from repro.kernels.chunked import chunked_attention
 from repro.kernels.indices import cap_block_mask
 from repro.kernels.ops import expand_kv
-from repro.models import common
+from repro.models import common, mla
 from repro.models.attention import AttnStats, resolved_attn_impl, rope_qk
 from repro.models.transformer import (
-    _ffn_apply,
+    _ffn_serve,
     _uses_moe,
     embed_tokens,
     logits_from_hidden,
@@ -73,8 +73,16 @@ CHUNK_ATTN_IMPLS = ("sparse", "chunked")
 class ChunkPrefillApi(NamedTuple):
     """Model-family entry points for chunked admission (``Model.prefill_chunk``).
 
-    ``None`` on families without the GQA stacked-cache layout (ssm, hybrid,
-    encdec, MLA) — the scheduler falls back to one-shot admission there.
+    ``None`` on families without a stacked-cache layout (ssm, hybrid,
+    encdec) — the scheduler falls back to one-shot admission there.
+
+    ``layer_begin`` returns ``(q, k, v, masks, decision, gate, perm)``;
+    ``decision`` is what it hands ``layer_end`` beside the masks
+    (SharePrefill's decision under GQA, the layer's latent rows under
+    MLA).  ``layer_end`` returns ``(x, kv, sp_state, stats, rows)``:
+    ``kv`` is what the cache keeps of the layer (``(k, v)`` under GQA,
+    the latent rows ``(B, S, latent_dim)`` under MLA) and ``rows`` the
+    rows each held expert took ``(B, held)``.
     """
     begin: Any
     layer_begin: Any
@@ -261,19 +269,19 @@ def chunk_prefill_layer_end(
     sequence length — o-proj, residuals, ln2, FFN (identical gemm shapes to
     the one-shot path), the vmapped dictionary update, and layer stats.
 
-    Returns ``(x, (k, v), sp_state, AttnStats)`` — the ``layer_prefill``
-    contract; the caller inserts ``(k, v)`` into its slot of the serving
-    cache.
+    Returns ``(x, (k, v), sp_state, AttnStats, rows)`` — the
+    ``layer_prefill`` contract; the caller inserts ``(k, v)`` into its
+    slot of the serving cache.
     """
     layer = _layer_params(params, layer_idx)
     out = shard(out, "batch", "heads")
     x = x + common.gqa_out(layer["attn"], out)
     h = common.rmsnorm(layer["ln2"], x, cfg.rms_norm_eps)
-    f, _ = _ffn_apply(layer, h, cfg, _uses_moe(cfg))
+    f, rows = _ffn_serve(layer, h, cfg, _uses_moe(cfg))
     x = x + f
 
     if masks is None:
-        return x, (k, v), sp_state, AttnStats.zero()
+        return x, (k, v), sp_state, AttnStats.zero(), rows
 
     if method == "share":
         cluster_ids = _layer_cluster_ids(cluster_arr, layer_idx)
@@ -284,14 +292,68 @@ def chunk_prefill_layer_end(
         ls = sa.layer_pattern_stats(masks, decision)
         stats = AttnStats(ls.num_shared, ls.num_dense, ls.num_vs,
                           ls.block_density, ls.max_row_pop)
-        return x, (k, v), sp_state, stats
+        return x, (k, v), sp_state, stats, rows
 
     h_q = masks.shape[1]
     stats = AttnStats(jnp.zeros(()), jnp.zeros(()),
                       jnp.asarray(float(h_q)),
                       jnp.mean(block_mask_density(masks)),
                       jnp.max(jnp.sum(masks.astype(jnp.float32), axis=-1)))
-    return x, (k, v), sp_state, stats
+    return x, (k, v), sp_state, stats, rows
+
+
+def _mla_layer(params, cfg: ModelConfig, layer_idx, fn):
+    """``fn(layer_params, moe_ffn)`` at a traced layer index over the
+    whole depth: the prefix layers (``prefix_<i>``, dense FFN) first, then
+    the scanned stack, chosen on the device so one program serves every
+    layer."""
+    n_prefix = num_prefix_layers(cfg)
+    stack = lambda: fn(_layer_params(params, layer_idx - n_prefix),
+                       _uses_moe(cfg))
+    if not n_prefix:
+        return stack()
+    branches = [lambda i=i: fn(params[f"prefix_{i}"], False)
+                for i in range(n_prefix)] + [stack]
+    return jax.lax.switch(jnp.minimum(layer_idx, n_prefix), branches)
+
+
+def mla_chunk_layer_begin(params, cfg: ModelConfig, layer_idx, x, positions,
+                          sp, sp_state, cluster_arr, *, method: str,
+                          attn_impl: str, seg_blocks=None):
+    """MLA quantum A: ln1, the decompressed per-head q/k (qk_nope +
+    qk_rope) and v for the dense ``attn`` quanta, and the layer's latent
+    rows for the page insert (handed on in the ``decision`` slot)."""
+    del sp, sp_state, cluster_arr, attn_impl, seg_blocks
+    if method != "dense":
+        raise ValueError(
+            f"chunked admission of MLA layers is dense only: method "
+            f"{method!r} (chunked share prefill is not implemented for "
+            "latent attention)")
+
+    def begin(layer, moe_ffn):
+        del moe_ffn
+        h = common.rmsnorm(layer["ln1"], x, cfg.rms_norm_eps)
+        return mla.prefill_qkv(layer["attn"], h, cfg, positions)
+
+    q, k, v, latent = _mla_layer(params, cfg, layer_idx, begin)
+    return q, k, v, None, latent, None, None
+
+
+def mla_chunk_layer_end(params, cfg: ModelConfig, layer_idx, x, out, k, v,
+                        a_tilde, masks, latent, sp, sp_state, cluster_arr,
+                        *, method: str):
+    """MLA quantum B: o-proj, residual, ln2 and the layer's FFN (dense on
+    the prefix layers, the held-expert layer on the stack)."""
+    del k, v, a_tilde, masks, sp, cluster_arr, method
+
+    def end(layer, moe_ffn):
+        y = x + mla.out_proj(layer["attn"], out)
+        h = common.rmsnorm(layer["ln2"], y, cfg.rms_norm_eps)
+        f, rows = _ffn_serve(layer, h, cfg, moe_ffn)
+        return y + f, rows
+
+    x, rows = _mla_layer(params, cfg, layer_idx, end)
+    return x, latent, sp_state, AttnStats.zero(), rows
 
 
 def chunk_prefill_finish(params, cfg: ModelConfig, x: jnp.ndarray,
@@ -312,23 +374,26 @@ def chunk_prefill_finish(params, cfg: ModelConfig, x: jnp.ndarray,
 def make_chunk_prefill(cfg: ModelConfig) -> Optional[ChunkPrefillApi]:
     """Bind the quantum entry points for a transformer-family config.
 
-    Returns ``None`` for layouts chunked admission cannot serve: MLA latent
-    caches (no per-layer GQA insert) and heterogeneous prefix stacks (the
-    quanta index the scanned stack only).
+    GQA configs index the scanned stack; MLA configs run every layer,
+    the dense prefix layers included, through the same quanta (the
+    latent cache takes one row per token and layer).  Returns ``None``
+    for a GQA stack behind prefix layers, which the quanta cannot index.
     """
-    if cfg.mla.enabled or num_prefix_layers(cfg) > 0:
+    latent = cfg.mla.enabled
+    if not latent and num_prefix_layers(cfg) > 0:
         return None
+    begin_fn = mla_chunk_layer_begin if latent else chunk_prefill_layer_begin
+    end_fn = mla_chunk_layer_end if latent else chunk_prefill_layer_end
     return ChunkPrefillApi(
         begin=lambda params, tokens: chunk_prefill_begin(params, cfg, tokens),
         layer_begin=lambda params, layer_idx, x, positions, sp, sp_state, \
-            cluster_arr, **kw: chunk_prefill_layer_begin(
+            cluster_arr, **kw: begin_fn(
                 params, cfg, layer_idx, x, positions, sp, sp_state,
                 cluster_arr, **kw),
         attn=lambda sp, q, k, v, masks, gate, perm, **kw: chunk_prefill_attn(
             cfg, sp, q, k, v, masks, gate, perm, **kw),
         layer_end=lambda params, layer_idx, x, out, k, v, a_tilde, masks, \
-            decision, sp, sp_state, cluster_arr, **kw: \
-            chunk_prefill_layer_end(
+            decision, sp, sp_state, cluster_arr, **kw: end_fn(
                 params, cfg, layer_idx, x, out, k, v, a_tilde, masks,
                 decision, sp, sp_state, cluster_arr, **kw),
         finish=lambda params, x, batch_idx, rows: chunk_prefill_finish(

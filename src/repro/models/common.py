@@ -6,6 +6,7 @@ stacks are built by stacking params along a leading "stack" dim and scanning.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -63,15 +64,50 @@ def rope_frequencies(dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature 0.1·mscale·ln(factor) + 1 (1 when the
+    positions are not scaled)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float,
+                     beta_slow: float) -> jnp.ndarray:
+    """(dim/2,) YaRN inverse frequencies (DeepSeek-V2's formulas): the
+    rope frequencies, interpolated by ``factor`` past the correction dims
+    of ``beta_slow`` rotations, kept below those of ``beta_fast``, with a
+    linear ramp between."""
+    def corr(rot):
+        return (dim * math.log(original_max / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_frequencies(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """Rotate (…, S, D) by per-token positions (…, S)."""
+               theta: float, inv: Optional[jnp.ndarray] = None,
+               scale: float = 1.0) -> jnp.ndarray:
+    """Rotate (…, S, D) by per-token positions (…, S); ``inv`` overrides
+    the plain inverse frequencies (YaRN) and ``scale`` multiplies cos and
+    sin."""
     if theta <= 0:
         return x
     d = x.shape[-1]
-    inv = rope_frequencies(d, theta)
+    if inv is None:
+        inv = rope_frequencies(d, theta)
     ang = positions[..., None].astype(jnp.float32) * inv      # (…, S, D/2)
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
     x1, x2 = jnp.split(jnp.asarray(x, jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)

@@ -7,10 +7,24 @@ attention affordable.  Decode uses the **absorbed** formulation (q_nope is
 pushed through W_uk so scores are taken directly against the latent cache);
 prefill decompresses so SharePrefill's per-head pattern logic sees ordinary
 per-head Q·K blocks (DESIGN.md §5).
+
+Rope is YaRN where the config scales positions (``MLAConfig.rope_factor``
+> 1, DeepSeek-V2's formulas): interpolated inverse frequencies, cos/sin
+times mscale(factor, mscale) / mscale(factor, mscale_all_dim), and a
+softmax scale of mscale(factor, mscale_all_dim)² / √(qk_nope + qk_rope).
+Prefill folds that temperature into q, so the attention kernels keep
+their 1/√d.
+
+Serving keeps one latent row per token, ``c_kv ‖ k_rope``
+(``MLAConfig.latent_dim`` wide), in the paged pool; the absorbed decode
+(:func:`mla_decode_paged`) is the dense paged decode of
+``models.attention`` as MQA over that row, whose first ``kv_lora_rank``
+columns are the value.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +35,9 @@ from repro.core import share_attention as sa
 from repro.distributed.sharding import shard
 from repro.kernels.chunked import chunked_attention
 from repro.models import common
-from repro.models.attention import AttnStats, resolve_attention_fn
+from repro.models.attention import (AttnStats, dense_decode,
+                                    paged_append, paged_pages,
+                                    resolve_attention_fn)
 
 
 def init_mla_layer(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32):
@@ -50,6 +66,33 @@ def init_mla_layer(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32):
     return params
 
 
+def _rope(x, positions, cfg: ModelConfig):
+    """Rotate the rotary stream (YaRN where the config scales positions)."""
+    m = cfg.mla
+    if m.rope_factor <= 1:
+        return common.apply_rope(x, positions, cfg.rope_theta)
+    inv = common.yarn_frequencies(
+        x.shape[-1], cfg.rope_theta, m.rope_factor,
+        m.rope_original_max_positions, m.beta_fast, m.beta_slow)
+    scale = (common.yarn_mscale(m.rope_factor, m.mscale)
+             / common.yarn_mscale(m.rope_factor, m.mscale_all_dim))
+    return common.apply_rope(x, positions, cfg.rope_theta, inv=inv,
+                             scale=scale)
+
+
+def q_temperature(cfg: ModelConfig) -> float:
+    """YaRN's softmax temperature, mscale(factor, mscale_all_dim)²: the
+    published softmax scale over 1/√(qk_nope + qk_rope)."""
+    m = cfg.mla
+    return common.yarn_mscale(m.rope_factor, m.mscale_all_dim) ** 2
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    m = cfg.mla
+    return q_temperature(cfg) / math.sqrt(m.qk_nope_head_dim
+                                          + m.qk_rope_head_dim)
+
+
 def _project_q(params, x, cfg: ModelConfig):
     m = cfg.mla
     if m.q_lora_rank:
@@ -70,7 +113,7 @@ def _project_kv_latent(params, x, cfg: ModelConfig, positions):
     c_kv = common.rmsnorm(params["kv_norm"], down[..., : m.kv_lora_rank],
                           cfg.rms_norm_eps)
     k_rope = down[..., m.kv_lora_rank:][:, None, :, :]   # (B,1,S,rope)
-    k_rope = common.apply_rope(k_rope, positions[:, None, :], cfg.rope_theta)
+    k_rope = _rope(k_rope, positions[:, None, :], cfg)
     return c_kv, k_rope
 
 
@@ -81,22 +124,38 @@ def _decompress(params, c_kv, cfg: ModelConfig):
     return shard(k_nope, "batch", "heads"), shard(v, "batch", "heads")
 
 
-def mla_train(params, x, cfg: ModelConfig, positions,
-              block_size: int = 128) -> jnp.ndarray:
+def prefill_qkv(params, x, cfg: ModelConfig, positions):
+    """Decompressed per-head ``q``/``k`` (qk_nope + qk_rope wide, q times
+    YaRN's temperature) and ``v``, and the latent rows ``(B, S,
+    latent_dim)`` the cache keeps."""
     m = cfg.mla
-    b, s, _ = x.shape
     q_nope, q_rope = _project_q(params, x, cfg)
-    q_rope = common.apply_rope(q_rope, positions[:, None, :], cfg.rope_theta)
+    q_rope = _rope(q_rope, positions[:, None, :], cfg)
     c_kv, k_rope = _project_kv_latent(params, x, cfg, positions)
     k_nope, v = _decompress(params, c_kv, cfg)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    t = q_temperature(cfg)
+    if t != 1.0:
+        q = jnp.asarray(jnp.asarray(q, jnp.float32) * t, q.dtype)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1]
                                   + (m.qk_rope_head_dim,))], axis=-1)
-    out, _ = chunked_attention(q, k, v, block_size=min(block_size, s),
-                               causal=True)
+    return q, k, v, jnp.concatenate([c_kv, k_rope[:, 0]], axis=-1)
+
+
+def out_proj(params, out):
+    """Attention output (B, H, S, v_head_dim) → (B, S, D)."""
     out = shard(out, "batch", "heads")
     return jnp.einsum("bhsk,hkd->bsd", out, params["wo"])
+
+
+def mla_train(params, x, cfg: ModelConfig, positions,
+              block_size: int = 128) -> jnp.ndarray:
+    s = x.shape[1]
+    q, k, v, _ = prefill_qkv(params, x, cfg, positions)
+    out, _ = chunked_attention(q, k, v, block_size=min(block_size, s),
+                               causal=True)
+    return out_proj(params, out)
 
 
 def mla_prefill(params, x, cfg: ModelConfig, positions, *,
@@ -106,15 +165,8 @@ def mla_prefill(params, x, cfg: ModelConfig, positions, *,
                 attn_width: Optional[int] = None):
     """Returns (y, cache=(c_kv, k_rope), new_state, stats)."""
     m = cfg.mla
-    b, s, _ = x.shape
-    q_nope, q_rope = _project_q(params, x, cfg)
-    q_rope = common.apply_rope(q_rope, positions[:, None, :], cfg.rope_theta)
-    c_kv, k_rope = _project_kv_latent(params, x, cfg, positions)
-    k_nope, v = _decompress(params, c_kv, cfg)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1]
-                                  + (m.qk_rope_head_dim,))], axis=-1)
+    s = x.shape[1]
+    q, k, v, latent = prefill_qkv(params, x, cfg, positions)
 
     use_sparse = method == "share" and sp.applicable(s)
     if use_sparse:
@@ -129,9 +181,9 @@ def mla_prefill(params, x, cfg: ModelConfig, positions, *,
         out, _ = chunked_attention(q, k, v, block_size=min(128, s),
                                    causal=True)
         new_state, stats = sp_state, AttnStats.zero()
-    out = shard(out, "batch", "heads")
-    y = jnp.einsum("bhsk,hkd->bsd", out, params["wo"])
-    return y, (c_kv, k_rope[:, 0]), new_state, stats
+    y = out_proj(params, out)
+    r = m.kv_lora_rank
+    return y, (latent[..., :r], latent[..., r:]), new_state, stats
 
 
 def mla_decode(params, x, cfg: ModelConfig,
@@ -139,11 +191,9 @@ def mla_decode(params, x, cfg: ModelConfig,
                cache_krope: jnp.ndarray,        # (B, S, rope_dim)
                pos: jnp.ndarray, positions):
     """Absorbed decode: score latent cache directly (perf note in DESIGN.md)."""
-    m = cfg.mla
-    b = x.shape[0]
     s = cache_ckv.shape[1]
     q_nope, q_rope = _project_q(params, x, cfg)          # (B,H,1,·)
-    q_rope = common.apply_rope(q_rope, positions[:, None, :], cfg.rope_theta)
+    q_rope = _rope(q_rope, positions[:, None, :], cfg)
     c_new, k_rope_new = _project_kv_latent(params, x, cfg, positions)
     cache_ckv = jax.lax.dynamic_update_slice_in_dim(
         cache_ckv, c_new, pos, axis=1)
@@ -153,9 +203,9 @@ def mla_decode(params, x, cfg: ModelConfig,
 
     # absorb W_uk into q: (B,H,1,R) scores against latent directly
     q_lat = jnp.einsum("bhqk,rhk->bhqr", q_nope, params["w_uk"])
-    scale = 1.0 / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
     logits = (jnp.einsum("bhqr,bsr->bhqs", q_lat, cache_ckv)
-              + jnp.einsum("bhqk,bsk->bhqs", q_rope, cache_krope)) * scale
+              + jnp.einsum("bhqk,bsk->bhqs", q_rope, cache_krope)
+              ) * softmax_scale(cfg)
     length_mask = jnp.arange(s) <= pos
     logits = jnp.where(length_mask[None, None, None, :], logits, -jnp.inf)
     p = jax.nn.softmax(jnp.asarray(logits, jnp.float32), axis=-1)
@@ -164,3 +214,37 @@ def mla_decode(params, x, cfg: ModelConfig,
     out = jnp.einsum("bhqr,rhk->bhqk", lat, params["w_uv"])
     y = jnp.einsum("bhqk,hkd->bqd", jnp.asarray(out, x.dtype), params["wo"])
     return y, (cache_ckv, cache_krope)
+
+
+def mla_decode_paged(params, x, cfg: ModelConfig, pool: jnp.ndarray,
+                     layer, pos: jnp.ndarray, positions, page_table,
+                     mask: Optional[jnp.ndarray] = None):
+    """Absorbed decode of one layer at per-slot positions against the
+    latent page pool ``(L, P, 1, page_size, latent_dim)``.
+
+    The step's latent row is written into each slot's current page at
+    ``[layer]`` (``attention.paged_append``), q_nope is taken through
+    W_uk, and the query ``q_lat ‖ q_rope`` attends the resident pages as
+    one key head (MQA) whose first ``kv_lora_rank`` columns are the value,
+    at the YaRN softmax scale; the latent result goes through W_uv and
+    W_o.  ``mask`` is ``(B, NB·page_size)`` in logical slot coordinates
+    (default: every slot up to ``pos``).  Returns ``(y (B, 1, D), pool)``."""
+    r = cfg.mla.kv_lora_rank
+    if mask is None:
+        sv = page_table.shape[1] * pool.shape[-2]
+        mask = jnp.arange(sv)[None, :] <= pos[:, None]
+    q_nope, q_rope = _project_q(params, x, cfg)          # (B,H,1,·)
+    q_rope = _rope(q_rope, positions[:, None, :], cfg)
+    c_new, k_rope_new = _project_kv_latent(params, x, cfg, positions)
+    row = jnp.concatenate([c_new, k_rope_new[:, 0]], axis=-1)  # (B,1,W)
+    pool = paged_append(pool, row[:, None], page_table, pos, (layer,))
+    q_lat = jnp.einsum("bhqk,rhk->bhqr", q_nope, params["w_uk"])
+    q = jnp.concatenate([q_lat, q_rope], axis=-1)[:, :, 0]    # (B,H,W)
+    # the whole row serves as the value and the result is cut to its
+    # first r columns: a sliced value operand is a second copy of the
+    # slots' pages (twice the step's temporaries on a v5e)
+    pages = paged_pages(pool, page_table, (layer,))
+    lat = dense_decode(q, pages, pages, mask, scale=softmax_scale(cfg))
+    out = jnp.einsum("bhr,rhk->bhk", jnp.asarray(lat[..., :r], x.dtype),
+                     params["w_uv"])
+    return jnp.einsum("bhk,hkd->bd", out, params["wo"])[:, None], pool

@@ -1,10 +1,19 @@
-"""Mixture-of-experts FFN with capacity-bucketed einsum dispatch.
+"""Mixture-of-experts FFN: the serving path's dropless held-expert layer and
+the training path's capacity-bucketed einsum dispatch.
 
-Mesh-TF-style dense dispatch: tokens are routed to ``top_k`` experts, each
-expert has a fixed capacity, and dispatch/combine are one-hot einsums — under
-GSPMD with experts sharded over ``model`` this lowers to the all-to-all
-exchange (DESIGN.md §7).  Covers Mixtral (8e top-2) and DeepSeek-V2 (2 shared
-+ 160 routed top-6).
+:func:`moe_held_apply` (prefill and decode) routes every token over all
+``num_experts`` (softmax, top-k, renormalized only where ``norm_topk``),
+keeps the assignments that fall on the experts this device holds
+(``MoEConfig.first_held`` onward, ``held`` of them), sorts them by expert
+and runs one grouped matmul (``jax.lax.ragged_dot``) per projection over
+exactly those rows: no token is dropped, and nothing stands in for the
+experts other devices hold.  Shared experts are added once.
+
+:func:`moe_apply` (training) is Mesh-TF-style dense dispatch: tokens are
+routed to ``top_k`` experts, each expert has a fixed capacity, and
+dispatch/combine are one-hot einsums — under GSPMD with experts sharded
+over ``model`` this lowers to the all-to-all exchange (DESIGN.md §7).
+Covers Mixtral (8e top-2) and DeepSeek-V2 (2 shared + 160 routed top-6).
 """
 from __future__ import annotations
 
@@ -37,14 +46,11 @@ def init_moe_layer(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32):
     params = {
         "router": common.dense_init(k1, (d, mo.num_experts), dtype),
         "w_gate": common.stack_init(
-            lambda kk: common.dense_init(kk, (d, f), dtype), k2,
-            mo.num_experts),
+            lambda kk: common.dense_init(kk, (d, f), dtype), k2, mo.held),
         "w_up": common.stack_init(
-            lambda kk: common.dense_init(kk, (d, f), dtype), k3,
-            mo.num_experts),
+            lambda kk: common.dense_init(kk, (d, f), dtype), k3, mo.held),
         "w_down": common.stack_init(
-            lambda kk: common.dense_init(kk, (f, d), dtype), k4,
-            mo.num_experts),
+            lambda kk: common.dense_init(kk, (f, d), dtype), k4, mo.held),
     }
     if mo.num_shared_experts:
         params["shared"] = common.init_mlp(
@@ -136,3 +142,72 @@ def moe_apply(params, x: jnp.ndarray, cfg: ModelConfig
     z = jnp.mean(jax.nn.logsumexp(jnp.asarray(logits, jnp.float32),
                                   axis=-1) ** 2)
     return jnp.asarray(y, x.dtype), MoEAux(lb, z, me)
+
+
+HELD_TOKENS = 4096      # tokens routed per grouped-matmul pass: the sorted
+                        # rows are O(HELD_TOKENS · top_k · d_model)
+
+
+def _held_pass(params, x, cfg: ModelConfig):
+    """One pass of :func:`moe_held_apply` over ``x`` (T, D): the held
+    experts' weighted outputs (T, D) f32 and each token's rows per held
+    expert (T, held)."""
+    mo = cfg.moe
+    t, d = x.shape
+    k, held = mo.top_k, mo.held
+    # the router scores in f32, as published
+    logits = jnp.einsum("td,de->te", jnp.asarray(x, jnp.float32),
+                        jnp.asarray(params["router"], jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, k)                        # (T, K)
+    if mo.norm_topk:
+        gate = gate / jnp.maximum(jnp.sum(gate, -1, keepdims=True), 1e-9)
+    local = idx - mo.first_held
+    mine = (local >= 0) & (local < held)
+    # held assignments first, grouped by expert; the rest sort last and
+    # fall outside every group
+    order = jnp.argsort(jnp.where(mine, local, held).reshape(-1),
+                        stable=True)
+    hot = jax.nn.one_hot(jnp.where(mine, local, held), held,
+                         dtype=jnp.int32)                       # (T, K, H)
+    per_token = hot.sum(axis=1)
+    sizes = per_token.sum(axis=0)
+    tok = order // k
+    rows = x[tok]                                               # (T·K, D)
+    adt = x.dtype
+    h = (jax.nn.silu(jax.lax.ragged_dot(rows, params["w_gate"], sizes,
+                                        preferred_element_type=jnp.float32))
+         * jax.lax.ragged_dot(rows, params["w_up"], sizes,
+                              preferred_element_type=jnp.float32))
+    out = jax.lax.ragged_dot(h.astype(adt), params["w_down"], sizes,
+                             preferred_element_type=jnp.float32)
+    live = jnp.arange(t * k) < jnp.sum(sizes)
+    w = jnp.where(live, gate.reshape(-1)[order], 0.0)
+    # rows past the groups are never written by the grouped matmul: select,
+    # so whatever they hold cannot reach the sum
+    y = jnp.zeros((t, d), jnp.float32).at[tok].add(
+        jnp.where(live[:, None], out * w[:, None], 0.0))
+    return y, per_token
+
+
+def moe_held_apply(params, x: jnp.ndarray, cfg: ModelConfig
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x: (B, S, D) → (B, S, D), and the rows each held expert took per
+    batch row ``(B, held)`` int32.
+
+    Dropless: every assignment to a held expert is computed.  Long inputs
+    run in passes of ``HELD_TOKENS`` tokens, so the sorted rows stay
+    bounded at 32k-token prefill."""
+    b, s, d = x.shape
+    flat = x.reshape(b * s, d)
+    n = b * s
+    if n <= HELD_TOKENS or n % HELD_TOKENS:
+        y, per_token = _held_pass(params, flat, cfg)
+    else:
+        y, per_token = jax.lax.map(
+            lambda xc: _held_pass(params, xc, cfg),
+            flat.reshape(n // HELD_TOKENS, HELD_TOKENS, d))
+    y = jnp.asarray(y.reshape(b, s, d), x.dtype)
+    if "shared" in params:
+        y = y + common.mlp(params["shared"], x)
+    return y, per_token.reshape(b, s, -1).sum(axis=1)

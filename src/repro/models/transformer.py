@@ -26,6 +26,9 @@ class PrefillResult(NamedTuple):
     cache: Any
     stats: attn.AttnStats
     sp_state: Any
+    # rows each held expert took per batch row, summed over MoE layers
+    # (B, held); None where no layer routes
+    expert_rows: Any = None
 
 
 def _uses_mla(cfg: ModelConfig) -> bool:
@@ -110,6 +113,16 @@ def _ffn_apply(layer, x, cfg: ModelConfig, moe_ffn: bool):
     return common.mlp(layer["ffn"], x), (jnp.zeros(()), jnp.zeros(()))
 
 
+def _ffn_serve(layer, x, cfg: ModelConfig, moe_ffn: bool):
+    """The serving FFN: the dropless held-expert layer on MoE layers, with
+    the rows each held expert took per batch row ``(B, held)`` (zeros on
+    dense layers)."""
+    if moe_ffn:
+        return moe.moe_held_apply(layer["ffn"], x, cfg)
+    return (common.mlp(layer["ffn"], x),
+            jnp.zeros((x.shape[0], cfg.moe.held), jnp.int32))
+
+
 def layer_train(layer, x, cfg: ModelConfig, positions, *, moe_ffn: bool):
     h = common.rmsnorm(layer["ln1"], x, cfg.rms_norm_eps)
     if _uses_mla(cfg):
@@ -138,14 +151,17 @@ def layer_prefill(layer, x, cfg: ModelConfig, positions, sp: SharePrefill,
             attn_width=attn_width)
     x = x + a
     h = common.rmsnorm(layer["ln2"], x, cfg.rms_norm_eps)
-    f, _ = _ffn_apply(layer, h, cfg, moe_ffn)
-    return x + f, cache, sp_state, stats
+    f, rows = _ffn_serve(layer, h, cfg, moe_ffn)
+    return x + f, cache, sp_state, stats, rows
 
 
 def layer_decode(layer, x, cfg: ModelConfig, cache, pos, positions, *,
                  moe_ffn: bool, window: int = 0, plan=None, valid=None,
                  decode_impl: str = "auto", page_table=None,
                  pool_layer=None, return_q: bool = False):
+    """One decode layer: ``(x, cache, rows)``, with the refresh query
+    window appended under ``return_q``; ``rows`` are the rows each held
+    expert took per batch row ``(B, held)`` (zeros on dense layers)."""
     window = window or cfg.sliding_window      # native SWA (Mixtral)
     h = common.rmsnorm(layer["ln1"], x, cfg.rms_norm_eps)
     if _uses_mla(cfg):
@@ -153,8 +169,14 @@ def layer_decode(layer, x, cfg: ModelConfig, cache, pos, positions, *,
             raise ValueError("return_q is a GQA decode contract (the "
                              "refresh query window); MLA layers never "
                              "carry a DecodePlan")
-        a, cache = mla.mla_decode(layer["attn"], h, cfg, cache[0], cache[1],
-                                  pos, positions)
+        if page_table is not None:
+            a, pool = mla.mla_decode_paged(
+                layer["attn"], h, cfg, cache[0], pool_layer, pos, positions,
+                page_table, valid)
+            cache = (pool,)
+        else:
+            a, cache = mla.mla_decode(layer["attn"], h, cfg, cache[0],
+                                      cache[1], pos, positions)
         a = a[:, None, :] if a.ndim == 2 else a
     else:
         res = attn.attention_decode(
@@ -165,10 +187,10 @@ def layer_decode(layer, x, cfg: ModelConfig, cache, pos, positions, *,
         a, cache = res[0], res[1]
     x = x + a
     h = common.rmsnorm(layer["ln2"], x, cfg.rms_norm_eps)
-    f, _ = _ffn_apply(layer, h, cfg, moe_ffn)
+    f, rows = _ffn_serve(layer, h, cfg, moe_ffn)
     if return_q:
-        return x + f, cache, res[2]
-    return x + f, cache
+        return x + f, cache, rows, res[2]
+    return x + f, cache, rows
 
 
 # --------------------------------------------------------------------------
@@ -230,36 +252,37 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray],
     prefix_caches = []
     for i in range(n_prefix):
         ids = cluster_arr[i] if cluster_arr is not None else None
-        x, cache, sp_state, _ = layer_prefill(
+        x, cache, sp_state, _, _ = layer_prefill(
             params[f"prefix_{i}"], x, cfg, positions, sp, sp_state, ids,
             method=method, moe_ffn=False, attn_impl=attn_impl,
             attn_width=attn_width)
         prefix_caches.append(cache)
 
     def body(carry, xs):
-        x, sp_state = carry
+        x, sp_state, rows = carry
         layer, ids = xs
-        x, cache, sp_state, stats = layer_prefill(
+        x, cache, sp_state, stats, r = layer_prefill(
             layer, x, cfg, positions, sp, sp_state, ids,
             method=method, moe_ffn=moe_ffn, attn_impl=attn_impl,
             attn_width=attn_width)
-        return (x, sp_state), (cache, stats)
+        return (x, sp_state, rows + r), (cache, stats)
 
     n_stack = cfg.num_layers - n_prefix
     ids_xs = (cluster_arr[n_prefix:] if cluster_arr is not None
               else jnp.zeros((n_stack, max(cfg.num_heads, 1)), jnp.int32))
-    (x, sp_state), (caches, stats) = jax.lax.scan(
-        body, (x, sp_state), (params["stack"], ids_xs))
+    rows0 = jnp.zeros((b, cfg.moe.held), jnp.int32)
+    (x, sp_state, rows), (caches, stats) = jax.lax.scan(
+        body, (x, sp_state, rows0), (params["stack"], ids_xs))
 
     if prompt_lens is None:
         last = x[:, -1, :]
     else:
-        rows = jnp.clip(prompt_lens, 1, s) - 1
-        last = x[jnp.arange(b), rows, :]
+        last_row = jnp.clip(prompt_lens, 1, s) - 1
+        last = x[jnp.arange(b), last_row, :]
     logits = logits_from_hidden(params, cfg, last)
     stats = attn.AttnStats.reduce_layers(stats)
     return PrefillResult(logits, {"prefix": prefix_caches, "stack": caches},
-                         stats, sp_state)
+                         stats, sp_state, rows if moe_ffn else None)
 
 
 def _cache_seq_len(cache) -> int:
@@ -281,6 +304,7 @@ def decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
                 decode_impl: str = "auto",
                 page_table: Optional[jnp.ndarray] = None,    # (B, NB) int32
                 collect_queries: bool = False,
+                expert_rows: bool = False,
                 ):
     """One decode step. token (B, 1) → logits (B, V), updated cache.
 
@@ -288,9 +312,9 @@ def decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
     serving) or a ``(B,)`` vector of per-slot positions (the continuous-
     batching scheduler: each slot decodes at its own position, so the rope
     position, the cache write, and the slot-validity mask are all per-row).
-    Vector ``pos`` is a GQA-cache contract — MLA latent caches keep the
-    scalar lockstep path (the dense carve-out; the scheduler routes MLA and
-    the non-transformer families through the legacy batch path).
+    Vector ``pos`` is a GQA-cache contract, or MLA's over the latent page
+    pool (``page_table``); contiguous MLA latent caches keep the scalar
+    lockstep path.
 
     ``plan`` enables decode-phase pattern sharing (beyond paper): prebuilt
     O(L·B·Hkv·NB) splash block tables derived once per batch from the
@@ -303,44 +327,48 @@ def decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
     carry a plan and keep dense latent-cache decode under any mesh).
     ``prompt_lens``/``prefill_len`` mark right-pad cache
     slots (positions in [prompt_len, prefill_len)) invalid so padded K/V is
-    never attended (ignored by MLA layers, which keep the plain length
-    mask); under the paged cache ``prefill_len`` is a ``(B,)`` vector —
-    slots of different former buckets coexist, each with its own prefill
-    boundary.
+    never attended (ignored by contiguous MLA layers, which keep the plain
+    length mask); under the paged cache ``prefill_len`` is a ``(B,)``
+    vector — slots of different former buckets coexist, each with its own
+    prefill boundary.
 
     ``page_table`` switches the cache contract to the block-paged pool:
     ``cache["stack"]`` leaves are then the shared ``(L, P, Hkv, ps, hd)``
-    page pools (prefix layers unsupported — the pool covers the scanned
-    stack) and each attention layer appends/reads through the table; the
-    virtual cache length is ``page_table.shape[1] · page_size``.  Dense
-    paged decode carries the whole pools through the layer loop, each
-    layer writing its pages in place at its layer index, so a donated
+    page pools (GQA: the scanned stack only, no prefix layers) or the one
+    latent pool ``(L, P, 1, ps, latent_dim)`` over every layer, prefix
+    layers first (MLA), and each attention layer appends/reads through the
+    table; the virtual cache length is ``page_table.shape[1] · page_size``.
+    Dense paged decode carries the whole pools through the layer loop,
+    each layer writing its pages in place at its layer index, so a donated
     pool is updated without a copy; the sparse plan path scans one
     layer's pool slice in and out.
 
     ``collect_queries`` additionally returns the step's per-layer
-    post-rope query vectors ``(L_stack, B, H, hd)`` as a third output
-    (the scan's ys) — the refresh query-window capture.  Plan-carrying
-    stack-only decode only (the refresh path is paged + sparse); the
-    default-off 2-tuple contract is unchanged."""
+    post-rope query vectors ``(L_stack, B, H, hd)`` (the scan's ys) — the
+    refresh query-window capture.  Plan-carrying stack-only decode only
+    (the refresh path is paged + sparse).  ``expert_rows`` appends, last,
+    the rows each held expert took per slot ``(B, held)``, summed over the
+    MoE layers.  With neither, the 2-tuple contract is unchanged."""
     b = (embeds.shape[0] if embeds is not None else token.shape[0])
     pos = jnp.asarray(pos)
-    if jnp.ndim(pos) and _uses_mla(cfg):
+    if jnp.ndim(pos) and _uses_mla(cfg) and page_table is None:
         raise ValueError(
-            "per-slot decode positions require the GQA cache layout; MLA "
-            "latent caches keep the lockstep scalar pos (dense carve-out — "
-            "serve them through the legacy batch path)")
-    if page_table is not None and (not jnp.ndim(pos) or _uses_mla(cfg)
-                                   or cache["prefix"]):
+            "per-slot decode positions require the GQA cache layout or the "
+            "latent page pool; contiguous MLA latent caches keep the "
+            "lockstep scalar pos")
+    if page_table is not None and (not jnp.ndim(pos) or cache["prefix"]
+                                   or (_uses_mla(cfg) and plan is not None)):
         raise ValueError(
-            "paged decode requires per-slot (vector) pos and a GQA "
-            "stack-only cache (no MLA / prefix layers)")
+            "paged decode requires per-slot (vector) pos and a stack-only "
+            "cache (the MLA latent pool holds the prefix layers and "
+            "decodes densely, with no DecodePlan)")
     if positions is None:
         positions = (pos[:, None] if jnp.ndim(pos)
                      else jnp.broadcast_to(pos[None, None], (b, 1)))
     x = embeds if embeds is not None else embed_tokens(params, cfg, token)
     moe_ffn = _uses_moe(cfg)
     n_prefix = num_prefix_layers(cfg)
+    rows = jnp.zeros((b, cfg.moe.held), jnp.int32)
 
     valid = None
     if prompt_lens is not None:
@@ -359,9 +387,9 @@ def decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
     for i, c in enumerate(cache["prefix"]):
         lp = (jax.tree.map(lambda a: a[i], plan)
               if plan is not None else None)
-        x, c = layer_decode(params[f"prefix_{i}"], x, cfg, c, pos, positions,
-                            moe_ffn=False, window=window, plan=lp,
-                            valid=valid, decode_impl=decode_impl)
+        x, c, _ = layer_decode(params[f"prefix_{i}"], x, cfg, c, pos,
+                               positions, moe_ffn=False, window=window,
+                               plan=lp, valid=valid, decode_impl=decode_impl)
         new_prefix.append(c)
 
     qs = None
@@ -373,63 +401,76 @@ def decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
                 raise ValueError("collect_queries covers the scanned stack "
                                  "only (no prefix layers)")
 
-            def body(x, xs):
+            def body(carry, xs):
+                x, rows = carry
                 layer, c, lp = xs
-                x, c, qv = layer_decode(layer, x, cfg, c, pos, positions,
-                                        moe_ffn=moe_ffn, window=window,
-                                        plan=lp, valid=valid,
-                                        decode_impl=decode_impl,
-                                        page_table=page_table,
-                                        return_q=True)
-                return x, (c, qv)
+                x, c, r, qv = layer_decode(layer, x, cfg, c, pos, positions,
+                                           moe_ffn=moe_ffn, window=window,
+                                           plan=lp, valid=valid,
+                                           decode_impl=decode_impl,
+                                           page_table=page_table,
+                                           return_q=True)
+                return (x, rows + r), (c, qv)
 
-            x, (new_caches, qs) = jax.lax.scan(
-                body, x, (params["stack"], cache["stack"], plan_xs))
+            (x, rows), (new_caches, qs) = jax.lax.scan(
+                body, (x, rows), (params["stack"], cache["stack"], plan_xs))
         else:
-            def body(x, xs):
+            def body(carry, xs):
+                x, rows = carry
                 layer, c, lp = xs
-                x, c = layer_decode(layer, x, cfg, c, pos, positions,
-                                    moe_ffn=moe_ffn, window=window, plan=lp,
-                                    valid=valid, decode_impl=decode_impl,
-                                    page_table=page_table)
-                return x, c
+                x, c, r = layer_decode(layer, x, cfg, c, pos, positions,
+                                       moe_ffn=moe_ffn, window=window,
+                                       plan=lp, valid=valid,
+                                       decode_impl=decode_impl,
+                                       page_table=page_table)
+                return (x, rows + r), c
 
-            x, new_caches = jax.lax.scan(
-                body, x, (params["stack"], cache["stack"], plan_xs))
+            (x, rows), new_caches = jax.lax.scan(
+                body, (x, rows), (params["stack"], cache["stack"], plan_xs))
     elif collect_queries:
         raise ValueError("collect_queries requires a DecodePlan (the "
                          "refresh path is sparse paged decode)")
     elif page_table is not None:
         # the pools ride in the carry, indexed by layer: no per-layer slice
-        # goes in and no stacked pool comes out
+        # goes in and no stacked pool comes out (the latent pool holds the
+        # prefix layers at its first layer indices)
+        pools = cache["stack"]
+        for i in range(n_prefix):
+            x, pools, _ = layer_decode(
+                params[f"prefix_{i}"], x, cfg, pools, pos, positions,
+                moe_ffn=False, window=window, valid=valid,
+                page_table=page_table, pool_layer=i)
+
         def body(carry, xs):
-            x, pool_k, pool_v = carry
+            x, pools, rows = carry
             layer, idx = xs
-            x, (pool_k, pool_v) = layer_decode(
-                layer, x, cfg, (pool_k, pool_v), pos, positions,
+            x, pools, r = layer_decode(
+                layer, x, cfg, pools, pos, positions,
                 moe_ffn=moe_ffn, window=window, valid=valid,
                 page_table=page_table, pool_layer=idx)
-            return (x, pool_k, pool_v), None
+            return (x, pools, rows + r), None
 
-        n_stack = cfg.num_layers - n_prefix
-        (x, pool_k, pool_v), _ = jax.lax.scan(
-            body, (x, *cache["stack"]),
-            (params["stack"], jnp.arange(n_stack)))
-        new_caches = (pool_k, pool_v)
+        (x, new_caches, rows), _ = jax.lax.scan(
+            body, (x, pools, rows),
+            (params["stack"], jnp.arange(n_prefix, cfg.num_layers)))
     else:
-        def body(x, xs):
+        def body(carry, xs):
+            x, rows = carry
             layer, c = xs
-            x, c = layer_decode(layer, x, cfg, c, pos, positions,
-                                moe_ffn=moe_ffn, window=window, valid=valid)
-            return x, c
+            x, c, r = layer_decode(layer, x, cfg, c, pos, positions,
+                                   moe_ffn=moe_ffn, window=window,
+                                   valid=valid)
+            return (x, rows + r), c
 
-        x, new_caches = jax.lax.scan(body, x,
-                                     (params["stack"], cache["stack"]))
+        (x, rows), new_caches = jax.lax.scan(
+            body, (x, rows), (params["stack"], cache["stack"]))
     logits = logits_from_hidden(params, cfg, x[:, -1, :])
-    new_cache = {"prefix": new_prefix, "stack": new_caches}
+    out = (logits, {"prefix": new_prefix, "stack": new_caches})
     if collect_queries:
-        return logits, new_cache, qs
-    return logits, new_cache
+        out += (qs,)
+    if expert_rows:
+        out += (rows,)
+    return out
 
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int,

@@ -15,15 +15,18 @@ long any admission can stall the occupied slots.
 
 Two events surface to the caller:
 
-``"kv"``   a layer's K/V just became final (``kv_layer``, ``kv``) — the
-           scheduler writes it into the admitted slot(s) immediately
+``"kv"``   a layer's K/V just became final (``kv_layer``, ``kv``: ``(k, v)``,
+           or the latent rows under MLA) — the scheduler writes it into
+           the admitted slot(s) immediately
            (:meth:`ServingEngine.cache_insert_layer`), per packed segment,
            while decode keeps running between quanta.
 ``"done"`` the final quantum ran: ``logits`` holds each segment's
            last-token logits (P, V), ``sp_state`` the post-prefill pattern
-           dictionary, ``attn_stats`` the layer-reduced pattern stats —
-           everything :class:`~repro.serving.scheduler.SlotScheduler` needs
-           to splice DecodePlan rows and sample first tokens.
+           dictionary, ``attn_stats`` the layer-reduced pattern stats,
+           ``expert_rows`` the rows each held expert took ``(1, held)``
+           summed over layers (None without experts) — everything
+           :class:`~repro.serving.scheduler.SlotScheduler` needs to
+           splice DecodePlan rows and sample first tokens.
 
 Packing (P > 1) concatenates same-bucket prompts into one ``(1, P·seq)``
 row: positions restart per segment, a block-diagonal segment mask isolates
@@ -98,6 +101,7 @@ class ChunkedPrefillRun:
         self.kv_layer = -1
         self.logits = None          # (P, V) after the finish quantum
         self.attn_stats: Optional[AttnStats] = None
+        self.expert_rows = None     # (1, held) summed over the layers so far
         self.quanta_done = 0
         self.quanta_total = 2 + self.num_layers * (2 + len(self.chunks))
 
@@ -124,6 +128,7 @@ class ChunkedPrefillRun:
         self._masks = self._decision = self._gate = self._perm = None
         self._outs, self._ats, self._layer_stats = [], [], []
         self.kv = None
+        self.expert_rows = None
         self.sp_state = None
         self.logits = None
         self._phase = "done"
@@ -166,10 +171,14 @@ class ChunkedPrefillRun:
         elif self._phase == "layer_end":
             li = jnp.int32(self.layer)
             ats = self._ats if self._ats else None
-            self.x, self.kv, self.sp_state, stats = self.fns["layer_end"](
+            (self.x, self.kv, self.sp_state, stats,
+             rows) = self.fns["layer_end"](
                 eng.params, li, self.x, self._outs, self._k, self._v, ats,
                 self._masks, self._decision, self.sp_state, self.cluster_arr)
             jax.block_until_ready(self.x)
+            if rows.shape[-1]:
+                self.expert_rows = (rows if self.expert_rows is None
+                                    else self.expert_rows + rows)
             self._layer_stats.append(stats)
             self.kv_layer = self.layer
             self._q = self._k = self._v = None

@@ -21,10 +21,10 @@ inverse of :meth:`ServingEngine.grow_cache`) and, under ``decode_sparse``,
 its DecodePlan row is spliced in-flight
 (``decode_plan.update_plan_slot`` / the Hkv-sharded variant) without
 touching the other slots' tables.  Request lifecycle and per-request
-metrics (queue time, TTFT, decode tokens/s) live in the scheduler; MLA
-latent caches and the non-transformer families keep the legacy
-batch-at-a-time path below (the dense carve-out — their caches have no
-per-slot write layout).
+metrics (queue time, TTFT, decode tokens/s) live in the scheduler.  MLA
+is served by the paged scheduler over its latent page pool; contiguous
+MLA latent caches and the non-transformer families keep the legacy
+batch-at-a-time path below (their caches have no per-slot write layout).
 
 **Step-cadence chunked admission.**  With ``prefill_chunk > 0`` the
 scheduler stops running admissions as monolithic prefill launches (which
@@ -231,6 +231,15 @@ class Request:
     # re-prefills the original prompt
     resume_tokens: List[int] = dataclasses.field(default_factory=list)
     pattern_stats: Optional[Dict[str, float]] = None
+    # rows routed to each held expert, summed over MoE layers, prefill
+    # and decode (int64 (held,); None for models without routed experts)
+    expert_rows: Optional[np.ndarray] = None
+
+    def add_expert_rows(self, rows) -> None:
+        rows = np.asarray(rows, np.int64).reshape(-1)
+        if rows.size:
+            self.expert_rows = (rows if self.expert_rows is None
+                                else self.expert_rows + rows)
 
     def metrics(self) -> Dict[str, float]:
         """Per-request serving metrics as one dict — the launcher summary
@@ -249,7 +258,18 @@ class Request:
             "tail_fraction": self.tail_fraction,
             "plan_traffic_fraction": self.plan_traffic_fraction,
             "refreshes": float(self.refreshes),
+            **self._expert_metrics(),
         }
+
+    def _expert_metrics(self) -> Dict[str, float]:
+        """Held-expert rows and the load: the busiest held expert's rows
+        over the mean."""
+        rows = self.expert_rows
+        if rows is None:
+            return {}
+        mean = float(rows.mean())
+        return {"expert_rows": float(rows.sum()),
+                "expert_load": float(rows.max()) / mean if mean else 0.0}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -285,8 +305,8 @@ class EngineConfig:
     width_safety: float = 1.25
     # slot-based continuous batching (repro.serving.scheduler): per-slot
     # decode positions, EOS early exit, immediate slot refill with in-flight
-    # cache/DecodePlan splicing.  Transformer families only — MLA and the
-    # non-transformer caches fall back to the legacy batch-at-a-time path.
+    # cache/DecodePlan splicing.  Transformer families only — contiguous MLA
+    # and the non-transformer caches fall back to the legacy batch path.
     scheduler: bool = False
     # step-cadence chunked admission (tokens per prefill quantum, rounded up
     # to the pattern block size; 0 = whole-sequence one-shot admission).
@@ -304,7 +324,8 @@ class EngineConfig:
     # shared page pool + per-slot page tables (page_size == block_size), ONE
     # cross-bucket scheduler over all requests, admission gated on pool
     # headroom.  Implies the scheduler; falls back to the legacy path on the
-    # non-scheduler families (MLA / ssm / hybrid / encdec).
+    # non-scheduler families (ssm / hybrid / encdec).  MLA pages its latent
+    # rows (one pool over every layer).
     paged: bool = False
     # page-pool capacity (pages, including the reserved null page 0);
     # 0 = auto-size so the pool can never run out for max_batch slots.
@@ -525,6 +546,7 @@ class ServingEngine:
         # the rules-context identity (same rationale as _prefill_fn).
         thread_lens = (self._transformer_family()
                        and not self.model.cfg.mla.enabled)
+        rows = self._expert_rows()
         key = (batch, seq, cache_len, sparse, thread_lens,
                current_rules())
         if key not in self._decode_cache:
@@ -535,12 +557,12 @@ class ServingEngine:
                     return self.model.decode(
                         params, token, cache, pos, plan=plan,
                         prompt_lens=plens, prefill_len=seq,
-                        decode_impl=self.ecfg.decode_impl)
+                        decode_impl=self.ecfg.decode_impl, **rows)
             elif thread_lens:
                 def decode_step(params, token, cache, pos, plens):
                     return self.model.decode(
                         params, token, cache, pos,
-                        prompt_lens=plens, prefill_len=seq)
+                        prompt_lens=plens, prefill_len=seq, **rows)
             else:
                 def decode_step(params, token, cache, pos, plens):
                     del plens
@@ -571,9 +593,11 @@ class ServingEngine:
         queries ``(L, B, H, hd)`` the scheduler rings up into each slot's
         recent-query window for strip re-estimation.  It is a separate
         cache entry — the default-off serve keeps replaying the exact
-        2-output program it always compiled."""
+        program it always compiled.  Models with routed experts return,
+        last, each slot's held-expert rows (:meth:`_expert_rows`)."""
         key = ("paged_q" if collect_queries else "paged", batch,
                table_blocks, sparse, current_rules())
+        rows = self._expert_rows()
         if key not in self._decode_cache:
             if sparse and collect_queries:
                 def decode_step(params, token, cache, page_table, pos,
@@ -583,7 +607,7 @@ class ServingEngine:
                         prompt_lens=plens, prefill_len=pflens,
                         page_table=page_table,
                         decode_impl=self.ecfg.decode_impl,
-                        collect_queries=True)
+                        collect_queries=True, **rows)
             elif sparse:
                 def decode_step(params, token, cache, page_table, pos,
                                 plens, pflens, plan):
@@ -591,7 +615,7 @@ class ServingEngine:
                         params, token, cache, pos, plan=plan,
                         prompt_lens=plens, prefill_len=pflens,
                         page_table=page_table,
-                        decode_impl=self.ecfg.decode_impl)
+                        decode_impl=self.ecfg.decode_impl, **rows)
             else:
                 if collect_queries:
                     raise ValueError(
@@ -601,10 +625,18 @@ class ServingEngine:
                                 plens, pflens):
                     return self.model.decode(
                         params, token, cache, pos, prompt_lens=plens,
-                        prefill_len=pflens, page_table=page_table)
+                        prefill_len=pflens, page_table=page_table, **rows)
             self._decode_cache[key] = jax.jit(decode_step,
                                               donate_argnames="cache")
         return self._decode_cache[key]
+
+    def _expert_rows(self) -> Dict[str, bool]:
+        """Decode keywords of the per-slot steps: a model with routed
+        experts returns, last, the rows each held expert took per slot,
+        which the scheduler adds to each request's counter."""
+        if self._transformer_family() and self.model.cfg.moe.enabled:
+            return {"expert_rows": True}
+        return {}
 
     def _chunk_tokens(self, seq: int) -> int:
         """Resolve the admission chunk size (tokens per prefill quantum) for
@@ -623,6 +655,11 @@ class ServingEngine:
             return 0
         if self.model.prefill_chunk is None:
             return 0
+        if self.model.cfg.mla.enabled and self.ecfg.method != "dense":
+            raise ValueError(
+                "chunked admission of MLA layers is dense only: method "
+                f"{self.ecfg.method!r} (chunked share prefill is not "
+                "implemented for latent attention; set prefill_chunk=0)")
         from repro.models.attention import resolved_attn_impl
         if resolved_attn_impl(self.ecfg.attn_impl) not in ("chunked",
                                                            "sparse"):
@@ -821,11 +858,12 @@ class ServingEngine:
 
     def _supports_scheduler(self) -> bool:
         """Slot-based continuous batching needs per-slot decode positions —
-        a GQA cache contract (per-row seq-axis writes + per-row validity).
-        MLA latent caches and the non-transformer families keep the legacy
-        batch-at-a-time path (the dense carve-out, same predicate as
-        :meth:`_supports_sparse_decode`)."""
-        return self._transformer_family() and not self.model.cfg.mla.enabled
+        a GQA cache contract (per-row seq-axis writes + per-row validity),
+        or MLA's latent page pool under ``paged``.  Contiguous MLA latent
+        caches and the non-transformer families keep the legacy
+        batch-at-a-time path."""
+        return self._transformer_family() and (
+            self.ecfg.paged or not self.model.cfg.mla.enabled)
 
     @staticmethod
     def grow_cache(cache, old_len: int, extra: int):
@@ -1089,11 +1127,11 @@ class ServingEngine:
             self.active_slot_steps += b - sum(done)
             tok_j = jnp.asarray(tok)[:, None]
             if use_sparse:
-                logits, cache = decode(self.params, tok_j, cache,
-                                       jnp.int32(seq + t), plens, plan)
+                logits, cache, *_ = decode(self.params, tok_j, cache,
+                                           jnp.int32(seq + t), plens, plan)
             else:
-                logits, cache = decode(self.params, tok_j, cache,
-                                       jnp.int32(seq + t), plens)
+                logits, cache, *_ = decode(self.params, tok_j, cache,
+                                           jnp.int32(seq + t), plens)
 
         for i, r in enumerate(grp):
             r.output_tokens = np.asarray(outs[i], np.int32)

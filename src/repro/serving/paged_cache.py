@@ -47,15 +47,21 @@ Conventions:
   bug where one page is later granted to two slots).
   :meth:`PageAllocator.check_consistency` audits the free-list/refcount
   partition; the test suite runs it after every scheduler-path test.
-* The pool covers the scanned transformer stack only (the families the
-  slot scheduler admits: dense/vlm/moe with GQA caches).  MLA latent
-  layouts keep the contiguous path.
+* The GQA pool covers the scanned transformer stack (dense/vlm/moe with
+  GQA caches).  An MLA pool is one latent pool
+  ``(num_layers, num_pages, 1, page_size, latent_dim)`` over every layer,
+  the dense prefix layers first: each token keeps one ``c_kv ‖ k_rope``
+  row per layer, no kv-head axis and no separate V.  Its prefill rows are
+  written by a jitted insert that takes the pool donated, so the pool is
+  never held twice.
 """
 from __future__ import annotations
 
+import functools
 from collections import Counter
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -213,12 +219,14 @@ def init_paged_pool(cfg, *, num_pages: int, page_size: int,
     its layer loop and writes each slot's current page at ``[layer,
     page]``, so the pool is updated in place and never held twice; the
     sparse twins scan one layer's ``(num_pages, Hkv, page_size,
-    head_dim)`` slice.
+    head_dim)`` slice.  MLA configs get the one latent pool
+    ``{"prefix": [], "stack": (latent,)}`` over all ``num_layers``.
     """
     from repro.models.transformer import num_prefix_layers
     if cfg.mla.enabled:
-        raise ValueError("paged KV cache requires GQA stack caches "
-                         "(MLA latent layouts keep the contiguous path)")
+        shape = (cfg.num_layers, num_pages, 1, page_size,
+                 cfg.mla.latent_dim)
+        return {"prefix": [], "stack": (jnp.zeros(shape, dtype),)}
     if num_prefix_layers(cfg):
         raise ValueError("paged KV cache covers the scanned stack only")
     hd = cfg.resolved_head_dim
@@ -239,9 +247,39 @@ def _scatter_whole(pool, val, pages):
     return pool.at[:, pages].set(v.astype(pool.dtype))
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_latent(pool, layers, rows, pages):
+    """pool[layers, pages] = rows ``(len(layers), S, W)`` page by page,
+    the pool donated."""
+    n, s, w = rows.shape
+    ps = pool.shape[3]
+    tiles = rows.reshape(n, s // ps, ps, w).astype(pool.dtype)
+    return pool.at[layers[:, None], pages[None, :], 0].set(tiles)
+
+
+def _insert_latent(cache, layers, rows, pages):
+    (pool,) = cache["stack"]
+    pool = _write_latent(pool, jnp.asarray(list(layers), jnp.int32), rows,
+                         jnp.asarray(pages, jnp.int32))
+    return {"prefix": [], "stack": (pool,)}
+
+
+def insert_latent_layer(cache, layer: int, rows, pages):
+    """Write one layer's prefill latent rows ``(1, S, latent_dim)`` into
+    pages of the latent pool (chunked admission, layer by layer)."""
+    return _insert_latent(cache, [layer], rows, pages)
+
+
 def insert_prefill(cache, new, pages):
     """Write a freshly prefilled request's stacked KV (leaves
-    ``(L, 1, Hkv, S, hd)``) into its ``S // page_size`` pages."""
+    ``(L, 1, Hkv, S, hd)``) into its ``S // page_size`` pages.  Under
+    MLA, ``new`` holds ``(c_kv, k_rope)`` per prefix layer and stacked,
+    and the pool takes their rows over every layer."""
+    if len(cache["stack"]) == 1:
+        rows = jnp.concatenate(
+            [jnp.concatenate(c, axis=-1) for c in new["prefix"]]
+            + [jnp.concatenate(new["stack"], axis=-1)[:, 0]])
+        return _insert_latent(cache, range(len(rows)), rows, pages)
     if new["prefix"]:
         raise ValueError("paged KV cache covers the scanned stack only")
     ck, cv = cache["stack"]
@@ -287,13 +325,15 @@ def copy_page(cache, src: int, dst: int):
     bounded by the decode-tail page count per request, and the pool is
     small on the CPU smoke configs this repo serves (donated-buffer jit
     would avoid the copy on accelerators if it ever matters)."""
-    ck, cv = cache["stack"]
-    return {"prefix": [], "stack": (ck.at[:, dst].set(ck[:, src]),
-                                    cv.at[:, dst].set(cv[:, src]))}
+    return {"prefix": [], "stack": tuple(p.at[:, dst].set(p[:, src])
+                                         for p in cache["stack"])}
 
 
 def page_bytes(cfg, page_size: int, itemsize: int = 4) -> int:
-    """Bytes one page holds across all layers, K and V."""
+    """Bytes one page holds across all layers, K and V (the latent row
+    under MLA)."""
+    if cfg.mla.enabled:
+        return cfg.num_layers * page_size * cfg.mla.latent_dim * itemsize
     return (2 * cfg.num_layers * cfg.num_kv_heads * page_size
             * cfg.resolved_head_dim * itemsize)
 

@@ -340,6 +340,8 @@ class SlotScheduler:
         # paging (decode_step accepts the (B,) vector form)
         self.pflens = np.full((self.nslots,), seq, np.int32)
         self.cache = None
+        # MLA pages its latent rows (one pool over every layer)
+        self.latent = self.paged and engine.model.cfg.mla.enabled
 
         # block-paged pool state: host-side free-list allocator + the
         # per-slot page table the paged kernels scalar-prefetch.  Every
@@ -1107,6 +1109,8 @@ class SlotScheduler:
 
         stats = eng._record_prefill_stats(result, width, seq)
         r.pattern_stats = stats
+        if result.expert_rows is not None:
+            r.add_expert_rows(result.expert_rows)
 
         if r.max_new_tokens <= 0:       # prefill-only: no token is emitted
             self._finish(_Slot(req=r, key=jax.random.PRNGKey(0), outs=[],
@@ -1439,14 +1443,21 @@ class SlotScheduler:
         """Write the just-finalized layer's K/V into the admitted slot(s)
         — incremental insert, while the other slots keep decoding."""
         eng = self.eng
-        k, v = run.kv
         if self.cache is None:
+            dt = jax.tree.leaves(run.kv)[0].dtype
             self.cache = (paged_cache.init_paged_pool(
                               eng.model.cfg, num_pages=self.num_pages,
-                              page_size=self.page_size, dtype=k.dtype)
+                              page_size=self.page_size, dtype=dt)
                           if self.paged else
                           eng.model.init_cache(self.nslots, self.cache_len,
-                                               dtype=k.dtype))
+                                               dtype=dt))
+        if self.latent:             # MLA admits dense, so never packed
+            pages = self.slot_pages[run.slot_ids[0]]
+            self.cache = paged_cache.insert_latent_layer(
+                self.cache, run.kv_layer, run.kv,
+                pages[: run.seq // self.page_size])
+            return
+        k, v = run.kv
         for j, slot in enumerate(run.slot_ids):
             if self.paged:
                 pages = self.slot_pages[slot][: run.seq // self.page_size]
@@ -1509,6 +1520,8 @@ class SlotScheduler:
             r.prefill_s = self._run_wall
             rstats = dict(stats)
             r.pattern_stats = rstats
+            if run.P == 1 and run.expert_rows is not None:
+                r.add_expert_rows(run.expert_rows)
 
             if not bool(np.isfinite(np.asarray(run.logits[j])).all()):
                 # per-segment quarantine at completion: this segment's
@@ -1640,13 +1653,10 @@ class SlotScheduler:
                 args = (eng.params, jnp.asarray(toks)[:, None], self.cache,
                         jnp.asarray(self.pos), jnp.asarray(self.plens))
         with tracing.span("decode_launch"):
-            qs = None
-            if self.refresh_on:
-                logits, self.cache, qs = decode(*args, self.plan)
-            elif self.use_sparse:
-                logits, self.cache = decode(*args, self.plan)
-            else:
-                logits, self.cache = decode(*args)
+            plan = (self.plan,) if self.use_sparse else ()
+            logits, self.cache, *extra = decode(*args, *plan)
+            qs = extra.pop(0) if self.refresh_on else None
+            rows = extra.pop(0) if extra else None
 
         # one device→host sync for the whole step: greedy rows (the
         # conformance-critical common case) take np.argmax on the pulled
@@ -1656,6 +1666,10 @@ class SlotScheduler:
         with tracing.span("decode_wait"):
             logits_h = np.asarray(logits)
             qs_h = None if qs is None else np.asarray(qs)
+            if rows is not None:
+                rows_h = np.asarray(rows)
+                for i in occ:
+                    self.slots[i].req.add_expert_rows(rows_h[i])
         with tracing.span("sample"):
             self._sample(occ, logits, logits_h, qs_h)
 

@@ -147,10 +147,16 @@ def test_write_slot_multi_axis():
 
 
 def test_init_paged_pool_rejects_mla():
+    """MLA gets no GQA K/V pool: its pool is the one latent pool over every
+    layer (the dense prefix layer included), one ``c_kv ‖ k_rope`` row per
+    token and no separate V."""
     cfg = get_smoke_config("deepseek-v2-236b")
     assert cfg.mla.enabled
-    with pytest.raises(ValueError, match="latent"):
-        paged_cache.init_paged_pool(cfg, num_pages=4, page_size=64)
+    cache = paged_cache.init_paged_pool(cfg, num_pages=4, page_size=64)
+    (pool,) = cache["stack"]
+    assert cache["prefix"] == []
+    assert pool.shape == (cfg.num_layers, 4, 1, 64, cfg.mla.latent_dim)
+    assert paged_cache.page_bytes(cfg, 64, 2) == pool[:, 0].size * 2
 
 
 # --------------------------------------------------------------------------

@@ -169,3 +169,60 @@ def test_paged_decode_step_updates_pool_in_place(one_chip):
             ops.add(m.group(2))
     assert "while" in ops
     assert ops <= {"parameter", "while", "get-tuple-element", "tuple"}, ops
+
+
+def test_latent_paged_decode_step_updates_pool_in_place(one_chip):
+    """The engine's latent paged decode program at DeepSeek-V2-Lite widths
+    (27 layers, the dense prefix layer's pages at layer 0 of the pool,
+    8 held of 64 routed experts, 8 slots, 260-page tables, a 521-page
+    bf16 pool of 576-wide latent rows) writes the pool in place: the
+    output aliases the whole pool, the largest temporary is one layer's
+    read of the slots' resident pages, and the only pool-shaped values of
+    the entry computation are its parameters, the layer loop and its
+    results (and bitcasts of them).  The held experts run as the grouped
+    matmul's kernel."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving import EngineConfig, ServingEngine
+
+    cfg = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                           num_held=8))
+    layers, pages, ps, nb, w = 27, 521, 128, (32768 + 512) // 128, 576
+    model = build_model(cfg)
+    eng = ServingEngine(model, None, model.default_share_prefill(),
+                        EngineConfig(method="dense", paged=True,
+                                     max_batch=SLOTS))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = jax.ShapeDtypeStruct((layers, pages, 1, ps, w), jnp.bfloat16,
+                                sharding=one_chip)
+    vec = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    compiled = eng._decode_fn_paged(SLOTS, nb).lower(
+        params, vec(SLOTS, 1), {"prefix": [], "stack": (pool,)},
+        vec(SLOTS, nb), vec(SLOTS), vec(SLOTS), vec(SLOTS)).compile()
+
+    pool_bytes = layers * pages * ps * w * 2
+    read_bytes = SLOTS * nb * ps * w * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < read_bytes + (8 << 20)
+
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    entry = text[text.index("\nENTRY "):]
+    shape = f"bf16[{layers},{pages},1,{ps},{w}]"
+    ops = set()
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][\w-]*)\(", line)
+        if m and shape in m.group(1):
+            ops.add(m.group(2))
+    assert "while" in ops
+    # a bitcast reinterprets the buffer in place; it is no copy
+    assert ops <= {"parameter", "while", "get-tuple-element", "tuple",
+                   "bitcast"}, ops
