@@ -11,9 +11,16 @@ against the Pallas kernel.
 Accepts an optional block mask: masked blocks contribute nothing to the
 softmax and carry −inf in the emitted Ã, token-for-token identical to the
 Pallas block-sparse kernel — but as a *dense* path it issues the FLOPs for
-every block.  It is the oracle and the fallback, not the hot path: the
-default SharePrefill backend is ``repro.kernels.sparse_attention_fn``, whose
-Pallas kernel skips inactive blocks (compute *and* DMA) on TPU.
+every block.  It is the oracle and the fallback, not the sparse hot path:
+the default SharePrefill backend is ``repro.kernels.sparse_attention_fn``,
+whose Pallas kernel skips inactive blocks (compute *and* DMA) on TPU.
+
+The dense chunked-admission quantum (``models/chunked_prefill.py``
+``chunk_prefill_attn`` without masks) reads only the keys of its chunk's
+causal frontier: on TPU through the Pallas flash kernel of
+``kernels/flash_prefill.py``, elsewhere (and under a sliding window, or on
+shapes the kernel cannot tile) through this function over the K/V sliced
+to that frontier.
 """
 from __future__ import annotations
 

@@ -8,7 +8,8 @@ engine can interleave with decode steps:
     begin                                   (embed)
     for each layer l:
         layer_begin(l)                      (ln1 + qkv + rope + mask staging)
-        attn(l, chunk_0) … attn(l, chunk_C) (rectangular Q-chunk × full-KV)
+        attn(l, chunk_0) … attn(l, chunk_C) (rectangular Q-chunk × KV; dense:
+                                             the chunk's causal frontier)
         layer_end(l)                        (o-proj + residual + ln2 + FFN,
                                              dictionary update, stats)
     finish                                  (last-token gather + lm head)
@@ -55,6 +56,7 @@ from repro.core.patterns import (
 from repro.distributed.sharding import shard
 from repro.kernels import batched_sparse_attention_fn
 from repro.kernels.chunked import chunked_attention
+from repro.kernels.flash_prefill import flash_chunk_attention, flash_tiles
 from repro.kernels.indices import cap_block_mask
 from repro.kernels.ops import expand_kv
 from repro.models import common, mla
@@ -102,6 +104,12 @@ def _layer_params(params, layer_idx):
 
 def _resolve_bs(sp: SharePrefill, n: int) -> int:
     return min(sp.cfg.block_size if sp.cfg.enabled else 128, n)
+
+
+def _flash_backend() -> bool:
+    """The dense chunk runs the Pallas flash kernel on TPU; elsewhere the
+    pure-JAX ``chunked_attention`` (the kernel only interprets there)."""
+    return jax.default_backend() == "tpu"
 
 
 def _layer_cluster_ids(cluster_arr, layer_idx):
@@ -195,11 +203,17 @@ def chunk_prefill_attn(
     chunk_blocks: int,              # q blocks in this chunk (static)
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Per-layer chunk quantum: attention output rows for q blocks
-    ``[chunk_start, chunk_start + chunk_blocks)`` against the FULL K/V.
+    ``[chunk_start, chunk_start + chunk_blocks)``.  The sparse paths take
+    the full K/V under their block masks; the dense path reads only the
+    keys of the chunk's causal frontier (the Pallas flash kernel of
+    ``kernels/flash_prefill.py`` on TPU when no sliding window is set and
+    the shapes tile, the sliced ``chunked_attention`` otherwise).
 
     Mirrors ``attention_prefill``'s backend dispatch structure op for op
     (same vmap nesting, same head permutation, same stats gating) so the
-    concatenated chunk outputs are bitwise the one-shot launch's rows.
+    concatenated chunk outputs are bitwise the one-shot launch's rows on
+    the pure-JAX paths (the flash kernel's P enters the PV matmul at bf16,
+    so its rows agree within bf16 rounding).
     Returns ``(out_rows (B,H,cn,Dv), a_rows (B,H,cnb,NB) | None)``.
     """
     impl = resolved_attn_impl(attn_impl)
@@ -214,8 +228,16 @@ def chunk_prefill_attn(
     q_c = jax.lax.slice_in_dim(q, off, off + cn, axis=2)
 
     if masks is None:
-        kx = common.repeat_kv(k, cfg.gqa_groups)
-        vx = common.repeat_kv(v, cfg.gqa_groups)
+        # dense: only the keys of the chunk's causal frontier [0, off + cn)
+        front = off + cn
+        if (not cfg.sliding_window and _flash_backend()
+                and flash_tiles(cn, front) is not None):
+            # the kernel's grid ends at the frontier: no K/V slice is copied
+            return flash_chunk_attention(q_c, k, v, q_offset=off), None
+        kx = common.repeat_kv(jax.lax.slice_in_dim(k, 0, front, axis=2),
+                              cfg.gqa_groups)
+        vx = common.repeat_kv(jax.lax.slice_in_dim(v, 0, front, axis=2),
+                              cfg.gqa_groups)
         out, _ = chunked_attention(
             q_c, kx, vx, block_size=bs, causal=True,
             window=cfg.sliding_window, q_offset=off)

@@ -226,3 +226,41 @@ def test_latent_paged_decode_step_updates_pool_in_place(one_chip):
     # a bitcast reinterprets the buffer in place; it is no copy
     assert ops <= {"parameter", "while", "get-tuple-element", "tuple",
                    "bitcast"}, ops
+
+
+@pytest.mark.parametrize("shape", [
+    ("internlm2-1.8b", 8192, 7), ("deepseek-v2-lite", 32768, 0),
+    ("deepseek-v2-lite", 32768, 31)], ids=["gqa-8k-c7", "mla-32k-c0",
+                                           "mla-32k-c31"])
+def test_dense_chunk_attn_quantum(one_chip, monkeypatch, shape):
+    """The dense ``attn`` quantum as the TPU runs it (the flash kernel over
+    the chunk's causal frontier) at internlm2-1.8b (16 / 8 heads, 128) and
+    DeepSeek-V2-Lite (16 heads, qk 192, v 128) widths: the kernel is there,
+    and the program holds no copy of K or V (at 32k one is 201 MB; MLA's
+    192-wide K is read in the layout XLA gives it) and no float32 scores
+    (a 1,024 x 32,768 tile per head is 2 GB over 16 heads)."""
+    import functools
+
+    from repro.configs import get_config
+    from repro.core.api import SharePrefill
+    from repro.kernels.flash_prefill import flash_chunk_attention
+    from repro.models import chunked_prefill as cp
+
+    name, n, chunk = shape
+    cfg = get_config(name)
+    h, hkv = cfg.num_heads, cfg.num_kv_heads
+    d, dv = ((cfg.mla.qk_nope_head_dim + cfg.mla.qk_rope_head_dim,
+              cfg.mla.v_head_dim) if cfg.mla.enabled else (D, D))
+    sp = SharePrefill.trivial(cfg.share_prefill, 1, h)
+    monkeypatch.setattr(cp, "_flash_backend", lambda: True)
+    monkeypatch.setattr(cp, "flash_chunk_attention", functools.partial(
+        flash_chunk_attention, interpret=False))
+    cb = CHUNK // sp.cfg.block_size
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in ((1, h, n, d), (1, hkv, n, d), (1, hkv, n, dv))]
+    compiled = jax.jit(lambda q, k, v: cp.chunk_prefill_attn(
+        cfg, sp, q, k, v, None, None, None, method="dense",
+        attn_impl="chunked", attn_width=None, chunk_start=chunk * cb,
+        chunk_blocks=cb)[0]).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
